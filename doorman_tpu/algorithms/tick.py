@@ -50,6 +50,7 @@ __all__ = [
     "proportional_fairness_tick",
     "oracle_row",
     "F32_PARITY_REL_BOUND",
+    "F64_TPU_REL_BOUND",
     "FILL_ITERS",
     "BALANCED_ROUNDS",
 ]
@@ -286,13 +287,15 @@ def balanced_theta(
     wants and leave the recursion (exactly one ratio class per round,
     mirroring the paper's one-job-removal recursion); the pool ratio
     takes over when it dominates — the convergence mask is the peel
-    set emptying. Truncation after `rounds` leaves capacity unclaimed
+    set emptying. A class that wants nothing has cap ratio x/0 = inf:
+    it is fixed (at 0) before the first round and never dilutes the
+    pool. Truncation after `rounds` leaves capacity unclaimed
     (the insensitivity tax; documented, and why BALANCED_FAIRNESS
     carries no Pareto-efficiency invariant)."""
     tiny = np.finfo(np.float64).tiny
     wants = np.asarray(wants, dtype=np.float64)
     x = np.asarray(weights, dtype=np.float64)
-    fixed = np.zeros(wants.shape, dtype=bool)
+    fixed = ~(wants > 0)
     remcap = float(capacity)
 
     def ratios(fixed):
@@ -336,6 +339,11 @@ def balanced_fairness_tick(
 # on-chip by bench.gate_pallas_kernels — both import it from here so a
 # re-characterization cannot desynchronize the two gates.
 F32_PARITY_REL_BOUND = 1e-6
+# f64 on a TPU is emulated and is not bit-identical to the oracles as
+# the CPU's is (BASELINE.md "parity ladder"): one ULP of the row scale
+# was measured on a v5e (PR 21). chip_smoke.py holds the f64 tick to
+# this bound there.
+F64_TPU_REL_BOUND = 1e-15
 
 
 def oracle_row(

@@ -9,7 +9,7 @@ See doc/chaos.md. The pieces:
                 lead, reconvergence)
   runner.py     drives the real stack through a plan, emits a verdict
   plans.py      shipped scenarios (master flap, etcd brownout, device
-                tunnel outage, intermediate partition)
+                outage, intermediate partition)
 """
 
 from doorman_tpu.chaos.clock import ChaosClock
@@ -21,7 +21,6 @@ from doorman_tpu.chaos.injectors import (
     FaultState,
     PortInjector,
     SolverInjector,
-    backend_probe_argv,
 )
 from doorman_tpu.chaos.invariants import InvariantChecker, Violation
 from doorman_tpu.chaos.plan import FaultEvent, FaultPlan
@@ -44,7 +43,6 @@ __all__ = [
     "SolverInjector",
     "SteppedElection",
     "Violation",
-    "backend_probe_argv",
     "get_plan",
     "run_plan",
 ]

@@ -39,7 +39,7 @@ KINDS = frozenset(
         "grpc_delay",       # params: {"seconds": s}
         "grpc_not_master",  # spurious NOT_MASTER: params: {"master": addr}
         # solver / backend seam (SolverInjector)
-        "solver_error",     # device solve raises (tunnel down)
+        "solver_error",     # device solve raises (device lost)
         "solver_slow",      # params: {"seconds": s} per solve
         "resident_overflow",# params: {"calls": n} ResidentOverflow per step
         "grant_corrupt",    # silently scale one row of the solve's
@@ -47,7 +47,6 @@ KINDS = frozenset(
                             # — the shadow auditor's prey
         # host seam
         "port_bind",        # action: bind a loopback port (stale server)
-        "backend_probe_fail",  # utils.backend probe argv fails
         # client seam (driven by the runner, not an injector): a swarm
         # of extra clients hammers GetCapacity refreshes every tick
         # while active; params: {"clients": n, "wants": w,

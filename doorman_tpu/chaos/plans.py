@@ -87,7 +87,7 @@ def etcd_brownout() -> FaultPlan:
     )
 
 
-def device_tunnel_outage() -> FaultPlan:
+def device_outage() -> FaultPlan:
     """Batch server on the resident tick path: the device solve dies
     mid-rotation for three ticks (tick errors, not crashes — stores
     keep serving last solved grants), then a ResidentOverflow forces
@@ -97,7 +97,7 @@ def device_tunnel_outage() -> FaultPlan:
     sampled oracle replay must report ZERO divergences (the
     grant_corruption plan is the dirty twin that must report some)."""
     return FaultPlan(
-        name="device_tunnel_outage",
+        name="device_outage",
         seed=3,
         setup={
             "servers": 1,
@@ -576,7 +576,7 @@ PLANS: Dict[str, "callable"] = {
     "frontend_worker_crash": frontend_worker_crash,
     "frontend_ring_stall": frontend_ring_stall,
     "grant_corruption": grant_corruption,
-    "device_tunnel_outage": device_tunnel_outage,
+    "device_outage": device_outage,
     "intermediate_partition": intermediate_partition,
     "shard_partition": shard_partition,
 }

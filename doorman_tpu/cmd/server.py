@@ -35,6 +35,7 @@ from doorman_tpu.server.election import (
 )
 from doorman_tpu.server.server import CapacityServer
 from doorman_tpu.utils import flagenv
+from doorman_tpu.utils.compile_cache import place_compile_cache
 
 log = logging.getLogger("doorman.server")
 
@@ -600,6 +601,7 @@ def main(argv=None) -> None:
         import jax
 
         jax.config.update("jax_platforms", args.jax_platform)
+    place_compile_cache()
     logging.basicConfig(
         level=getattr(logging, args.log_level.upper(), logging.INFO),
         format="%(asctime)s %(levelname).1s %(name)s: %(message)s",

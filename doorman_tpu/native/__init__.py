@@ -15,6 +15,7 @@ Python store.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -32,7 +33,11 @@ from doorman_tpu.core.store import ClientLeaseStatus, ResourceLeaseStatus
 log = logging.getLogger(__name__)
 
 _SRC = Path(__file__).resolve().parent / "store.cc"
-_LIB = Path(__file__).resolve().parent / "_store.so"
+# The library is named by a hash of its source, never trusted by mtime:
+# a checkout copied with a foreign or stale `.so` beside it rebuilds.
+_LIB = _SRC.parent / (
+    "_store.%s.so" % hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+)
 
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None" = None
@@ -188,15 +193,9 @@ def _load() -> "ctypes.CDLL | None":
         if _lib is not None or _load_failed:
             return _lib
         try:
-            if (not _LIB.exists()
-                    or _LIB.stat().st_mtime < _SRC.stat().st_mtime):
+            if not _LIB.exists():
                 _build()
-            try:
-                lib = ctypes.CDLL(str(_LIB))
-            except OSError:
-                # A stale or foreign-platform .so; rebuild once and retry.
-                _build()
-                lib = ctypes.CDLL(str(_LIB))
+            lib = ctypes.CDLL(str(_LIB))
             _declare(lib)
             _lib = lib
         except Exception:

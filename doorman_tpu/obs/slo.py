@@ -413,20 +413,12 @@ def tpu_tick_budget_spec(name: str = "tpu_tick_p50_ms") -> SloSpec:
     )
 
 
-def tpu_tick_verdict(p50_ms: float, *, cpu_fallback: bool) -> dict:
-    """Evaluate the standing TPU tick budget for one bench round.
-    CPU-fallback rounds yield an honest no_data verdict (the scalar is
-    withheld — the target is a hardware claim); accelerator rounds
-    report pass/fail automatically."""
+def tpu_tick_verdict(p50_ms: float) -> dict:
+    """Evaluate the standing TPU tick budget for one bench round (the
+    bench runs only on a TPU, so the target is always measurable)."""
     spec = tpu_tick_budget_spec()
-    scalars = {} if cpu_fallback else {"tick_p50_ms": float(p50_ms)}
-    verdict = SloEngine([spec]).evaluate(SloInputs(scalars=scalars))[0]
-    if cpu_fallback:
-        verdict["detail"] = {
-            "reason": "cpu_fallback: hardware target not measurable",
-            "cpu_p50_ms": round(float(p50_ms), 3),
-        }
-    return verdict
+    scalars = {"tick_p50_ms": float(p50_ms)}
+    return SloEngine([spec]).evaluate(SloInputs(scalars=scalars))[0]
 
 
 def storm_slo_verdicts(

@@ -1,42 +1,19 @@
-"""Version-compat shims for JAX APIs that moved between releases.
+"""`jax.shard_map` with the replication check off.
 
-`shard_map` graduated from `jax.experimental.shard_map` to the top-level
-`jax` namespace (and the experimental module is slated for removal), so no
-single import spelling works across the JAX versions this framework
-supports. Every in-repo user imports it from here; tests import through
-`doorman_tpu.parallel`, so a wrong spelling would break collection of the
-whole sharded suite, not just one test.
-
-The wrapper also disables the static replication checker (`check_rep`,
-renamed `check_vma` in newer releases) by default: the solvers run
-`psum`-combined scans whose carries the checker cannot type (it reports
-"Scan carry input and output got mismatched replication types" and
-suggests exactly this flag), while the numerics are pinned independently
-against the single-chip solve in tests/test_sharded.py. Callers can still
-pass the flag explicitly to re-enable the check.
+Every in-repo user imports shard_map from here. The solvers run
+`psum`-combined scans whose carries the static replication checker
+cannot type (it reports "Scan carry input and output got mismatched
+replication types" and suggests exactly this flag); the numerics are
+pinned independently against the single-chip solve in
+tests/test_sharded.py. Callers can pass `check_vma=True` to re-enable it.
 """
 
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.5: top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = next(
-    (
-        kw
-        for kw in ("check_rep", "check_vma")
-        if kw in inspect.signature(_shard_map).parameters
-    ),
-    None,
-)
+import jax
 
 
 def shard_map(f, *args, **kwargs):
-    """`jax.shard_map` with the replication check off unless overridden."""
-    if _CHECK_KW is not None:
-        kwargs.setdefault(_CHECK_KW, False)
-    return _shard_map(f, *args, **kwargs)
+    """`jax.shard_map` with `check_vma=False` unless overridden."""
+    kwargs.setdefault("check_vma", False)
+    return jax.shard_map(f, *args, **kwargs)

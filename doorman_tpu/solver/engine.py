@@ -713,6 +713,10 @@ class TickEngineBase:
         # loud, but never fatal to the tick unless the caller raises.
         self.on_anomaly: "Callable[[str, dict], None] | None" = None
         self._tick_fns: Dict[tuple, Callable] = {}
+        # Names of the pallas kernels traced into this engine's tick
+        # programs (added at trace time, so once per compile): the
+        # proof that a tick on the chip did not take the XLA solve.
+        self.traced_kernels: set = set()
         # Per-phase wall-time accumulators (seconds) for the perf
         # breakdown; bench.py reports them per tick, and every lap also
         # lands in the default metrics registry and the trace ring
@@ -721,6 +725,11 @@ class TickEngineBase:
         # while a tick in an executor thread updates values — the dict
         # never resizes, only stores floats.
         self.phase_s: Dict[str, float] = {name: 0.0 for name in PHASES}
+
+    def _traced(self, kernel: Callable) -> Callable:
+        """`kernel`, recorded in `traced_kernels` (call while tracing)."""
+        self.traced_kernels.add(kernel.__name__)
+        return kernel
 
     # -- configuration ------------------------------------------------
 

@@ -199,9 +199,12 @@ def balanced_fill(
     classes at the max cap ratio freeze at their wants and leave the
     recursion — the peel condition compares ratios to their own segmax,
     so the argmax class peels by exact float equality (guaranteed
-    progress, no epsilon). BALANCED_ROUNDS bounds the unroll; an
-    unconverged row leaves capacity unclaimed (the insensitivity
-    truncation — still feasible, documented in doc/algorithms.md).
+    progress, no epsilon). A class that wants nothing (ratio x/0 =
+    inf) starts fixed at 0, so it never dilutes the pool; a subnormal
+    want, which the device flushes to 0, lands there too.
+    BALANCED_ROUNDS bounds the unroll; an unconverged row leaves
+    capacity unclaimed (the insensitivity truncation — still feasible,
+    documented in doc/algorithms.md).
     Overload form only; the caller's fits-where handles underload."""
     dtype = wants.dtype
     zero = jnp.zeros((), dtype)
@@ -233,8 +236,9 @@ def balanced_fill(
         remcap = remcap - segsum(jnp.where(peel, wants, zero))
         return fixed, remcap
 
+    fixed0 = jnp.where(wants > 0, zero, one)
     fixed, remcap = jax.lax.fori_loop(
-        0, BALANCED_ROUNDS, body, (jnp.zeros_like(wants), capacity)
+        0, BALANCED_ROUNDS, body, (fixed0, capacity)
     )
     cap_ratio, _ratio, max_ratio = ratios(fixed, remcap)
     theta = jnp.maximum(cap_ratio, max_ratio)

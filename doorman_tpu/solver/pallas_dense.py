@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 from doorman_tpu.solver.dense import DenseBatch
 from doorman_tpu.solver.lanes import solve_lanes
 from doorman_tpu.solver.pallas_common import (
+    FUSED_LIVE_TILES,
     LANE,
     col_spec,
     pad_col,
@@ -80,7 +81,13 @@ def _fused_kernel(wants_ref, has_ref, sub_ref, active_ref, cap_ref,
     prev = prev_ref[:]
     out = gets.astype(prev.dtype)
     deliv = deliv_ref[:] > 0  # [T, 1] column: delivered this tick
-    diff = jnp.any(out != prev, axis=1, keepdims=True)
+    # Compare and reduce in f32: widening is exact, so equality is the
+    # same as in prev's dtype, and Mosaic cannot relayout a packed
+    # (bf16) i1 mask for an any-reduction.
+    ne = out.astype(jnp.float32) != prev.astype(jnp.float32)
+    diff = jnp.max(
+        jnp.where(ne, jnp.float32(1), jnp.float32(0)), axis=1, keepdims=True
+    ) > 0
     changed_ref[:] = jnp.where(
         deliv & diff,
         jnp.ones((), wants_ref.dtype),
@@ -166,7 +173,9 @@ def fused_tick_pallas(
     dtype = batch.wants.dtype
     kpad = (-K) % LANE
     Kp = K + kpad
-    tile_r = tile_rows(R, Kp, jnp.dtype(dtype).itemsize)
+    tile_r = tile_rows(
+        R, Kp, jnp.dtype(dtype).itemsize, live_tiles=FUSED_LIVE_TILES
+    )
     rpad = (-R) % tile_r
     Rp = R + rpad
 

@@ -1,9 +1,9 @@
-"""Host<->device transfer helpers for high-latency device links.
+"""Host<->device transfer helpers.
 
 A single `jax.device_get` of a large array serializes one copy stream;
-tunneled/remote device links (and to a lesser degree PCIe) only reach
-full bandwidth with several async copies in flight. `chunked_device_get`
-splits the copy along the leading axis and overlaps the pieces.
+a device link may reach full bandwidth only with several async copies
+in flight (not yet measured on the v5e). `chunked_device_get` splits
+the copy along the leading axis and overlaps the pieces.
 """
 
 from __future__ import annotations

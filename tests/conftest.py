@@ -25,6 +25,10 @@ if "xla_force_host_platform_device_count" not in xla_flags:
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
+# Tests keep JAX's persistent compilation cache off, in this process
+# and in the servers they spawn (the entry points place it otherwise).
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 def pytest_configure(config):

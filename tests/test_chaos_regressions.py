@@ -9,9 +9,6 @@ subsystem's replay artifact) instead of a bespoke fixture:
     flapping under load;
   * stale-port detection: tools/drives ensure_ports_free fails LOUDLY
     when a leaked server still holds the port;
-  * backend-probe retry classification: utils.backend.wait_for_backend
-    rides out a transient tunnel blip but fails fast on unretryable
-    environment breakage;
   * ResidentOverflow clears BOTH resident handles, so a fallback tick
     cannot be overwritten by one-tick-stale wide grants.
 """
@@ -31,10 +28,8 @@ from doorman_tpu.chaos import (
     FaultState,
     PortInjector,
     SolverInjector,
-    backend_probe_argv,
 )
 from doorman_tpu.server.election import EtcdKV, KVElection
-from doorman_tpu.utils.backend import wait_for_backend
 from tests.fake_etcd import FakeEtcd
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -131,37 +126,6 @@ def test_stale_port_detected_by_ensure_ports_free():
     finally:
         ports.release_all()
     common.ensure_ports_free(bound[0])  # freed: no complaint
-
-
-def test_backend_probe_rides_out_transient_blip():
-    """A fast RuntimeError probe failure (what a down tunnel surfaces)
-    stays retryable: with the fault budgeted to one probe, the second
-    attempt succeeds and wait_for_backend returns None."""
-    state = FaultState(0)
-    state.start(FaultEvent(
-        at_tick=0, kind="backend_probe_fail", duration_ticks=10,
-        params={"calls": 1, "mode": "tunnel_down"},
-    ))
-    reason = wait_for_backend(
-        attempts=2, per_timeout_s=0.5,
-        probe_argv=lambda: backend_probe_argv(state),
-    )
-    assert reason is None
-
-
-def test_backend_probe_fails_fast_on_unretryable_breakage():
-    """Environment breakage (ModuleNotFoundError) must NOT burn the
-    paced retry schedule — it reports within one attempt."""
-    state = FaultState(0)
-    state.start(FaultEvent(
-        at_tick=0, kind="backend_probe_fail", duration_ticks=10,
-        params={"mode": "unretryable"},
-    ))
-    reason = wait_for_backend(
-        attempts=3, per_timeout_s=30.0,
-        probe_argv=lambda: backend_probe_argv(state),
-    )
-    assert reason is not None and "ModuleNotFoundError" in reason
 
 
 def test_resident_overflow_clears_both_resident_handles():

@@ -197,8 +197,8 @@ def test_etcd_brownout_survives_single_hiccup_then_relearns(verdicts):
     assert changes[-1][1] == ["s0"]
 
 
-def test_device_tunnel_outage_degrades_to_tick_errors(verdicts):
-    v = verdicts["device_tunnel_outage"]
+def test_device_outage_degrades_to_tick_errors(verdicts):
+    v = verdicts["device_outage"]
     errors = [e for e in v["event_log"] if e[1] == "tick_error"]
     # The dead backend surfaces as per-tick errors, never as a
     # violation or a crash; serving continued from the stores.
@@ -290,11 +290,11 @@ def test_grant_corruption_verdict_is_byte_stable(verdicts):
 
 def test_clean_plan_pins_audit_silence(verdicts):
     """The other half of the audit acceptance: a fault plan that never
-    corrupts grants (device_tunnel_outage runs the same auditor at the
+    corrupts grants (device_outage runs the same auditor at the
     same cadence) reports zero divergences and zero anomalies — the
     auditor does not cry wolf through solver outages, slow solves, or
     resident overflows."""
-    v = verdicts["device_tunnel_outage"]
+    v = verdicts["device_outage"]
     audit = v["audit"]["s0"]
     assert audit["divergences"] == 0 and audit["details"] == []
     assert audit["samples"] > 0  # it actually ran

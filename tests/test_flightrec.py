@@ -217,7 +217,7 @@ def test_tick_exception_auto_dumps(tmp_path):
         await server.tick_once()  # one healthy record
 
         async def boom():
-            raise RuntimeError("device tunnel died")
+            raise RuntimeError("device lost")
 
         server._tick_once_locked = boom
         try:
@@ -228,7 +228,7 @@ def test_tick_exception_auto_dumps(tmp_path):
 
         assert server.flightrec.last_dump["reason"] == "tick_exception"
         recs = server.flightrec.snapshot()
-        assert "RuntimeError: device tunnel died" in recs[-1]["error"]
+        assert "RuntimeError: device lost" in recs[-1]["error"]
         dumped = [
             p for p in os.listdir(tmp_path)
             if "tick_exception" in p and p.endswith(".json")

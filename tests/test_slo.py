@@ -6,6 +6,8 @@ ingested as metrics)."""
 
 import json
 
+import pytest
+
 import tests.conftest  # noqa: F401
 
 from doorman_tpu.obs import metrics as metrics_mod
@@ -177,24 +179,17 @@ def test_bench_verdict_applies_to_wall_ms_rows():
 
 def test_tpu_tick_budget_is_a_standing_spec():
     """The <10 ms one-chip target (ROADMAP "Sub-10 ms TPU tick") is a
-    standing SloSpec: accelerator rounds report pass/fail
-    automatically, CPU-fallback rounds yield an HONEST no_data verdict
-    (never a fail that would poison the trajectory deltas) while still
-    recording the CPU number in the detail."""
+    standing SloSpec: every bench round (always on a TPU) reports
+    pass/fail automatically."""
     spec = slo.tpu_tick_budget_spec()
     assert spec.target == slo.TPU_TICK_BUDGET_MS == 10.0
     assert spec.kind == "max"
 
-    v = slo.tpu_tick_verdict(7.5, cpu_fallback=False)
+    v = slo.tpu_tick_verdict(7.5)
     assert v["status"] == "pass" and v["margin"] == 2.5
 
-    v = slo.tpu_tick_verdict(12.0, cpu_fallback=False)
+    v = slo.tpu_tick_verdict(12.0)
     assert v["status"] == "fail"
-
-    v = slo.tpu_tick_verdict(44.0, cpu_fallback=True)
-    assert v["status"] == "no_data"
-    assert v["observed"] is None
-    assert v["detail"]["cpu_p50_ms"] == 44.0
 
 
 # ----------------------------------------------------------------------
@@ -269,42 +264,19 @@ def test_trajectory_on_missing_dir_is_empty(tmp_path):
     assert comp.delta({"metric": "x", "value": 1.0}) is None
 
 
-def test_bench_cpu_fallback_tags_every_row(monkeypatch):
-    """The r04/r05 fix: an engaged CPU fallback pins the backend env
-    BEFORE any in-process jax use, lands a diagnostic (never a metric
-    row), and tags every subsequently emitted metric row."""
-    import os
+def test_bench_requires_tpu(monkeypatch):
+    """bench.py measures on a TPU or not at all: on the CPU backend its
+    device check raises before any stage runs, and no row is emitted."""
     import sys
     from pathlib import Path
 
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     import bench
 
-    # Sandbox bench's process-global state and env for this test.
-    monkeypatch.setattr(bench, "_CPU_FALLBACK", "")
-    monkeypatch.setattr(bench, "_DIAGNOSTICS", [])
     monkeypatch.setattr(bench, "_EMITTED", [])
-    monkeypatch.setattr(bench, "write_artifact", lambda **kw: None)
-    monkeypatch.setenv(
-        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "cpu")
-    )
-    if "XLA_FLAGS" in os.environ:
-        monkeypatch.setenv("XLA_FLAGS", os.environ["XLA_FLAGS"])
-    else:
-        monkeypatch.delenv("XLA_FLAGS", raising=False)
-
-    bench._engage_cpu_fallback("backend_unreachable", "probe timed out")
-    assert os.environ["JAX_PLATFORMS"] == "cpu"
-    assert "--xla_force_host_platform_device_count" in os.environ[
-        "XLA_FLAGS"
-    ]
-    # The fallback itself is a diagnostic, never a metric row.
-    assert bench._DIAGNOSTICS[-1]["diagnostic"] == "cpu_fallback"
-    assert "metric" not in bench._DIAGNOSTICS[-1]
-
-    row = {"metric": "server_tick_1m_leases_native_store_wall_ms",
-           "value": 50.0, "unit": "ms"}
-    bench._annotate_row(row)
-    assert row["cpu_fallback"] == "backend_unreachable"
-    assert row["slo"]["status"] == "pass"
-    assert row["delta_vs_prev"] is None or "round" in row["delta_vs_prev"]
+    monkeypatch.setattr(bench, "_PLATFORM_INFO", {})
+    with pytest.raises(RuntimeError, match="measures on a TPU"):
+        bench.require_tpu()
+    with pytest.raises(RuntimeError, match="compiles for a TPU"):
+        bench.gate_pallas_kernels()
+    assert bench._EMITTED == []

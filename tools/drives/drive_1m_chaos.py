@@ -5,7 +5,7 @@ The server's own tick loop drives the ticks."""
 import asyncio
 import time
 
-from _common import pin_platform_in_process, require_backend, load_1m
+from _common import pin_platform_in_process, load_1m
 
 CFG = """
 resources:
@@ -95,6 +95,5 @@ async def main():
     await server.stop()
 
 
-require_backend()
 pin_platform_in_process()
 asyncio.run(main())

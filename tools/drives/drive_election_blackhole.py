@@ -9,11 +9,10 @@ import subprocess
 import sys
 import time
 
-from _common import ensure_ports_free, platform_args, require_backend, REPO, spawn, stop, tail, write_config
+from _common import ensure_ports_free, platform_args, REPO, spawn, stop, tail, write_config
 
 from tests.fake_etcd import FakeEtcd
 
-require_backend()
 
 blackhole = socket.socket()
 blackhole.bind(("127.0.0.1", 0))

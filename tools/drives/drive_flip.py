@@ -7,11 +7,10 @@ import subprocess
 import sys
 import time
 
-from _common import ensure_ports_free, platform_args, require_backend, REPO, spawn, stop, tail, write_config
+from _common import ensure_ports_free, platform_args, REPO, spawn, stop, tail, write_config
 
 from tests.fake_etcd import FakeEtcd
 
-require_backend()
 
 fake = FakeEtcd()
 fake.start()

@@ -5,7 +5,7 @@ import time
 
 import numpy as np
 
-from _common import pin_platform_in_process, NUM_RES, PER_RES, require_backend
+from _common import pin_platform_in_process, NUM_RES, PER_RES
 
 async def main():
     from doorman_tpu import native
@@ -59,6 +59,5 @@ async def main():
     assert solver.idle_ticks == before, "write did not resume real ticks"
     print("IDLE 1M OK")
 
-require_backend()
 pin_platform_in_process()
 asyncio.run(main())

@@ -6,9 +6,8 @@ import sys
 import time
 import urllib.request
 
-from _common import platform_args, require_backend, spawn as _spawn, stop, tail, write_config
+from _common import platform_args, spawn as _spawn, stop, tail, write_config
 
-require_backend()
 
 cfg = write_config("""
 resources:

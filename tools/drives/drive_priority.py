@@ -5,9 +5,8 @@ import os
 import sys
 import time
 
-from _common import platform_args, require_backend, spawn, stop, tail, write_config
+from _common import platform_args, spawn, stop, tail, write_config
 
-require_backend()
 
 cfg = write_config("""
 groups:

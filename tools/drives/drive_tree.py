@@ -10,9 +10,8 @@ import sys
 import time
 import urllib.request
 
-from _common import ensure_ports_free, platform_args, require_backend, spawn, stop, tail, write_config
+from _common import ensure_ports_free, platform_args, spawn, stop, tail, write_config
 
-require_backend()
 
 cfg = write_config("""
 resources:

@@ -26,9 +26,8 @@ import sys
 import time
 import urllib.request
 
-from _common import ensure_ports_free, platform_args, require_backend, spawn, stop, tail, write_config
+from _common import ensure_ports_free, platform_args, spawn, stop, tail, write_config
 
-require_backend()
 
 ROOT_CAP = 400.0
 N_HI, N_LO, WANTS = 4, 8, 40.0

@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from _common import pin_platform_in_process, require_backend, PLATFORM
+from _common import pin_platform_in_process, PLATFORM
 
 WIDE_CLIENTS = 50_000 if PLATFORM == "cpu" else 1_000_000
 NARROW_RES = 5
@@ -176,6 +176,5 @@ resources:
     await server.stop()
 
 
-require_backend()
 pin_platform_in_process()
 asyncio.run(main())

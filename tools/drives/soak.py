@@ -10,14 +10,13 @@ import os
 import sys
 import time
 
-from _common import ensure_ports_free, platform_args, require_backend, spawn, stop, tail, write_config
+from _common import ensure_ports_free, platform_args, spawn, stop, tail, write_config
 
 from tests.fake_etcd import FakeEtcd
 
 DURATION = 600.0
 FLIP_EVERY = 75.0
 
-require_backend()
 
 fake = FakeEtcd()
 fake.start()
