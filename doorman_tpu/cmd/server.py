@@ -74,8 +74,10 @@ def make_parser() -> argparse.ArgumentParser:
                         "bit-for-bit; f32 is TPU-native and enables the "
                         "fused pallas kernels")
     p.add_argument("--profile-dir", default="",
-                   help="batch mode: write a JAX profiler trace of the "
-                        "first --profile-ticks ticks to this directory")
+                   help="batch mode: write a JAX profiler trace of "
+                        "--profile-ticks ticks, from the first applied "
+                        "tick on, with the program's tick spans, to this "
+                        "directory")
     p.add_argument("--profile-ticks", type=int, default=8)
     p.add_argument("--trace", action="store_true",
                    help="enable the span tracer: client/server/solver "

@@ -13,9 +13,10 @@ additionally publishes every lap as:
   * a last-tick gauge (`doorman_tick_phase_last_seconds{component,
     phase}`) — the most recent tick's breakdown at a glance;
   * a span in the trace ring (category `phase`) when the tracer is
-    enabled, parented to whatever span is current (the server's tick
-    span propagates into the executor thread via copy_context), so a
-    Perfetto timeline shows the tick with its phase children.
+    enabled or a profiler capture is running, parented to whatever span
+    is current (the server's tick spans propagate into the executor
+    thread via copy_context), so a Perfetto timeline shows the tick with
+    its phase children.
 
 Buckets are tuned for sub-tick phases (tens of microseconds to one
 second); the default request buckets start at 5 ms and would flatten
@@ -150,7 +151,7 @@ class PhaseRecorder:
         self._hist.observe(seconds, self._component, phase)
         self._last.set(seconds, self._component, phase)
         tracer = trace_mod.default_tracer()
-        if tracer.enabled:
+        if tracer.recording():
             tracer.add_complete(
                 phase,
                 ts_us=trace_mod.perf_to_us(end - seconds),

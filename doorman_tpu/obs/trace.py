@@ -8,10 +8,23 @@ exports them in the Chrome trace-event JSON format that Perfetto
 
 Design constraints, in order:
 
-  * disabled means FREE: the tracer ships enabled on nobody. `span()` on
-    a disabled tracer returns one shared no-op context manager — no
-    allocation, no clock read — so instrumentation can stay inline in
+  * off means FREE: the tracer ships enabled on nobody. `span()` on a
+    disabled tracer with no profiler capture running returns one shared
+    no-op context manager — no allocation, no clock read, one check of
+    the profiler's capture flag — so instrumentation can stay inline in
     hot paths (RPC handlers, per-tick solver phases).
+  * a capture turns it on: while a `jax.profiler` capture records in
+    this process (`capture_active()`), spans, phase laps and the
+    process's garbage-collection pauses (complete events named
+    `GC_EVENT`) record into the ring even with the tracer disabled, so
+    every capture — the benchmark's traced run, an operator's
+    `--profile-dir` — holds the program's own timeline.
+  * one clock with the device trace: while a capture records, every
+    context-managed span also enters a `jax.profiler.TraceAnnotation` of
+    the same name, so the span sits on the capture's host timeline,
+    nested in whatever annotation the caller opened, next to the device
+    ops it launched. Laps timed after the fact (`add_complete`) go to
+    the ring only.
   * enabled means CHEAP: one perf_counter read on enter, one on exit,
     one deque append (the ring drops oldest on overflow). Budget is
     single-digit microseconds per span; tests/test_trace.py pins it
@@ -29,6 +42,9 @@ Design constraints, in order:
     threads, which wall clocks are not. (Cross-process traces align by
     span parentage, not by ts.)
 
+This module never imports jax: it asks the profiler about a capture only
+once `jax.profiler` is loaded, and a process without it has none.
+
 Unclosed spans are tracked: `open_spans()` returns whatever entered but
 never exited, and the tier-1 tests assert every instrumented path leaves
 it empty — a leaked span means a code path skipped its __exit__.
@@ -38,21 +54,25 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 __all__ = [
+    "GC_EVENT",
     "KNOWN_INSTANT_NAMES",
     "KNOWN_SPAN_NAMES",
     "TRACE_METADATA_KEY",
     "Span",
     "SpanContext",
     "Tracer",
+    "capture_active",
     "default_tracer",
     "grpc_metadata",
     "jax_capture",
@@ -77,6 +97,20 @@ TRACE_METADATA_KEY = "doorman-trace"
 # (solver/engine.py PHASES).
 KNOWN_SPAN_NAMES = frozenset({
     "server.tick",
+    # The Tick driver's sections (CapacityServer.tick_once): the direct
+    # children of server.tick, stream.fanout among them. tick.offload is
+    # the executor hop as the event loop sees it; tick.engine is the
+    # engine work inside the executor, and engine.collect /
+    # engine.dispatch its two pipeline stages, which hold the phase laps.
+    "tick.partition",
+    "tick.offload",
+    "tick.engine",
+    "tick.persist",
+    "tick.audit",
+    "tick.flightrec",
+    "tick.admission",
+    "engine.collect",
+    "engine.dispatch",
     "server.parent_refresh",
     "server.*",  # per-RPC handler spans: server.GetCapacity, ...
     "client.refresh",
@@ -128,9 +162,35 @@ KNOWN_INSTANT_NAMES = frozenset({
     "fleet.report",
 })
 
+# The complete event a garbage-collection pause records while a capture
+# runs (args: the generation), parented to the span it interrupted.
+GC_EVENT = "gc"
+
 # The process time axis: perf_counter at import. Chrome trace `ts` must
 # be monotonic; wall clocks step and skew.
 _EPOCH = time.perf_counter()
+
+# jax.profiler.TraceAnnotation and its capture flag, bound on the first
+# check after jax.profiler is loaded; until then no capture can run.
+_trace_me = None
+
+
+def _unbound() -> bool:
+    global _trace_me, _is_enabled
+    tm = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    if tm is None:
+        return False
+    _trace_me, _is_enabled = tm, tm.is_enabled
+    return tm.is_enabled()
+
+
+_is_enabled = _unbound
+
+
+def capture_active() -> bool:
+    """True while a jax.profiler capture is recording in this process
+    (TraceMe.is_enabled(): a flag read, well under a microsecond)."""
+    return _is_enabled()
 
 
 def now_us() -> float:
@@ -221,12 +281,13 @@ NOOP_SPAN = _NOOP
 
 
 class _ActiveSpan:
-    """Context manager for one live span (enabled tracer only)."""
+    """Context manager for one live span (enabled tracer or a capture
+    running); `mirror` also enters a profiler annotation of the name."""
 
     __slots__ = ("_tracer", "_name", "_cat", "_args", "_parent",
-                 "_rec", "_token")
+                 "_rec", "_token", "_mirror", "_ann")
 
-    def __init__(self, tracer, name, cat, args, parent):
+    def __init__(self, tracer, name, cat, args, parent, mirror):
         self._tracer = tracer
         self._name = name
         self._cat = cat
@@ -234,8 +295,13 @@ class _ActiveSpan:
         self._parent = parent
         self._rec = None
         self._token = None
+        self._mirror = mirror
+        self._ann = None
 
     def __enter__(self):
+        if self._mirror:
+            self._ann = _trace_me(self._name)
+            self._ann.__enter__()
         tr = self._tracer
         parent = self._parent if self._parent is not None else _current.get()
         span_id = next(tr._ids)
@@ -265,6 +331,8 @@ class _ActiveSpan:
             tr._open.pop(rec.span_id, None)
         tr._events.append(rec)
         _current.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -281,10 +349,17 @@ class Tracer:
         self.capacity = capacity
         self._events: deque = deque(maxlen=capacity)
         self._open: Dict[int, Span] = {}
-        self._open_lock = threading.Lock()
+        # Reentrant: a collection can start inside a locked block, and the
+        # gc hook's event then takes the lock again on the same thread.
+        self._open_lock = threading.RLock()
         self._ids = itertools.count(1)
         self._tids: Dict[int, int] = {}
         self._tnames: Dict[int, str] = {}
+        # The gc.callbacks hook, live only while a capture records (see
+        # _hook_gc); _gc_t0 is the running pause's start.
+        self._gc_hook = self._on_gc
+        self._gc_hooked = False
+        self._gc_t0: Optional[float] = None
 
     # -- lifecycle -----------------------------------------------------
 
@@ -305,15 +380,27 @@ class Tracer:
 
     # -- recording -----------------------------------------------------
 
+    def recording(self) -> bool:
+        """Whether spans and laps record now: the tracer is enabled, or
+        a profiler capture is running."""
+        capturing = _is_enabled()
+        if capturing is not self._gc_hooked:
+            self._hook_gc(capturing)
+        return capturing or self.enabled
+
     def span(self, name: str, cat: str = "", args: Optional[dict] = None,
              parent: Optional[SpanContext] = None):
         """Context manager timing a block. No-op (and no allocation)
-        while disabled. `parent` overrides the ambient context — pass
-        the remote parent extracted from gRPC metadata on the server
-        side of a hop."""
-        if not self.enabled:
+        while disabled with no capture running; under a capture it also
+        marks the block on the capture's host timeline. `parent`
+        overrides the ambient context — pass the remote parent
+        extracted from gRPC metadata on the server side of a hop."""
+        mirror = _is_enabled()  # inline: this is the hot off path
+        if mirror is not self._gc_hooked:
+            self._hook_gc(mirror)
+        if not (mirror or self.enabled):
             return _NOOP
-        return _ActiveSpan(self, name, cat, args, parent)
+        return _ActiveSpan(self, name, cat, args, parent, mirror)
 
     def instant(self, name: str, cat: str = "",
                 args: Optional[dict] = None) -> None:
@@ -336,8 +423,10 @@ class Tracer:
                      parent: Optional[SpanContext] = None) -> None:
         """Record an already-measured interval (solver phase laps time
         themselves with perf_counter and report here afterwards)."""
-        if not self.enabled:
-            return
+        if self.recording():
+            self._complete(name, ts_us, dur_us, cat, args, parent)
+
+    def _complete(self, name, ts_us, dur_us, cat, args, parent) -> None:
         ctx = parent if parent is not None else _current.get()
         span_id = next(self._ids)
         if ctx is not None:
@@ -383,6 +472,31 @@ class Tracer:
         return json.dumps(self.chrome_trace(extra_events))
 
     # -- internals -----------------------------------------------------
+
+    def _hook_gc(self, on: bool) -> None:
+        """Keep the gc hook in step with the capture flag: it is in
+        gc.callbacks only while a capture records. A hook left in for
+        good would cost a Python call per collection, and a busy server
+        collects about a hundred times a tick."""
+        with self._open_lock:
+            if on and self._gc_hook not in gc.callbacks:
+                gc.callbacks.append(self._gc_hook)
+            elif not on and self._gc_hook in gc.callbacks:
+                gc.callbacks.remove(self._gc_hook)
+            self._gc_hooked = on
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """gc.callbacks hook: one GC_EVENT complete event per pause.
+        Never touches gc.callbacks: the collector is iterating it."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is not None and _trace_me.is_enabled():
+            self._complete(
+                GC_EVENT, perf_to_us(t0), (time.perf_counter() - t0) * 1e6,
+                "gc", {"generation": info["generation"]}, None,
+            )
 
     def _tid(self) -> int:
         ident = threading.get_ident()

@@ -396,6 +396,7 @@ class CapacityServer(CapacityServicer):
         self.profile_ticks = profile_ticks
         self._profiling = False
         self._profile_done = False
+        self._profile_from = 0  # applied ticks when the capture started
         if solver_dtype not in ("f32", "f64"):
             raise ValueError(f"unknown solver dtype {solver_dtype!r}")
         # f64 is the oracle-parity default; f32 trades exact parity for
@@ -944,167 +945,206 @@ class CapacityServer(CapacityServicer):
         tick racing the loop's must queue, not corrupt."""
         async with self._tick_lock:
             tick_start = self._clock()
-            try:
-                with trace_mod.default_tracer().span(
-                    "server.tick", cat="tick",
-                    args={"server": self.id,
-                          "resources": len(self.resources)},
-                ):
+            tracer = trace_mod.default_tracer()
+            with tracer.span(
+                "server.tick", cat="tick",
+                args={"server": self.id, "resources": len(self.resources)},
+            ):
+                try:
                     await self._tick_once_locked()
                     # The tick pipeline is the batch server's durability
                     # beat: flush this tick's journal deltas and take the
                     # cadenced snapshot inside the tick span.
-                    self.persist_step()
+                    with tracer.span("tick.persist", cat="tick"):
+                        self.persist_step()
                     # Tick-edge stream fanout: push lease deltas to
                     # WatchCapacity subscribers of the rows this tick
                     # moved (the fanout's decides are journal deltas of
                     # the NEXT flush beat).
                     self.push_streams()
-            except Exception as exc:
-                # The black box's trigger: an unhandled tick exception
-                # dumps the last N ticks before the error propagates
-                # (to _tick_loop's log, or the chaos runner's
-                # tick_error entry).
-                self._flight_abort(tick_start, exc)
-                raise
-            # Shadow audit BEFORE the flight record so an inline
-            # auditor's fresh divergence count rides this very tick's
-            # record (the executor-backed live default may lag a tick
-            # — the counter is cumulative either way).
-            self._audit_step()
-            self._flight_record_tick(tick_start)
-            if self._admission is not None:
-                # Tick lag feeds the overload controller: a solve
-                # falling behind its cadence is overload even while
-                # the RPC path still looks healthy. Measured on the
-                # server clock so chaos replays stay deterministic.
-                self._admission.controller.observe_tick_lag(
-                    (self._clock() - tick_start)
-                    / max(self.tick_interval, 1e-9)
-                )
+                except Exception as exc:
+                    # The black box's trigger: an unhandled tick exception
+                    # dumps the last N ticks before the error propagates
+                    # (to _tick_loop's log, or the chaos runner's
+                    # tick_error entry).
+                    self._flight_abort(tick_start, exc)
+                    raise
+                # Shadow audit BEFORE the flight record so an inline
+                # auditor's fresh divergence count rides this very tick's
+                # record (the executor-backed live default may lag a tick
+                # — the counter is cumulative either way).
+                with tracer.span("tick.audit", cat="tick"):
+                    self._audit_step()
+                with tracer.span("tick.flightrec", cat="tick"):
+                    self._flight_record_tick(tick_start)
+                if self._admission is not None:
+                    # Tick lag feeds the overload controller: a solve
+                    # falling behind its cadence is overload even while
+                    # the RPC path still looks healthy. Measured on the
+                    # server clock so chaos replays stay deterministic.
+                    with tracer.span("tick.admission", cat="tick"):
+                        self._admission.controller.observe_tick_lag(
+                            (self._clock() - tick_start)
+                            / max(self.tick_interval, 1e-9)
+                        )
+            self._profile_between_ticks()
 
     async def _tick_once_locked(self) -> None:
         if not self.resources:
             return
-        solver = self._get_solver()
-        if self.profile_dir and not self._profiling and not self._profile_done:
-            import jax
+        with trace_mod.default_tracer().span("tick.partition", cat="tick"):
+            solver = self._get_solver()
+            resources = list(self.resources.values())
+            work = self._tick_work(solver, resources)
+        if work is not None:
+            await self._offload(work)
+        else:
+            self._stream_check_all = True
+            snap = solver.prepare(resources)
+            gets = await self._offload(solver.solve, snap)
+            solver.apply(resources, snap, gets, return_grants=False)
 
-            try:
-                jax.profiler.start_trace(self.profile_dir)
-                self._profiling = True
-            except Exception:
-                # E.g. another trace already active in this process; the
-                # capture is best-effort and must never block solving.
-                log.exception("%s: profiler capture unavailable", self.id)
-                self._profile_done = True
-        resources = list(self.resources.values())
-        loop = asyncio.get_running_loop()
+    async def _offload(self, fn, *args):
+        """fn(*args) in an executor thread: `tick.offload` times it on
+        the event loop, hop included, and `tick.engine` the call itself
+        in the thread."""
+        tracer = trace_mod.default_tracer()
+
+        def engine():
+            with tracer.span("tick.engine", cat="tick"):
+                return fn(*args)
+
+        with tracer.span("tick.offload", cat="tick"):
+            # copy_context: executor threads don't inherit contextvars,
+            # and the engine's spans must nest under this one.
+            ctx = contextvars.copy_context()
+            return await asyncio.get_running_loop().run_in_executor(
+                None, ctx.run, engine
+            )
+
+    def _tick_work(self, solver, resources: List[Resource]):
+        """The tick's engine work as one callable for the executor, with
+        the lanes partitioned here, on the event loop; None for a python
+        store, whose snapshot pack and write-back stay on the loop."""
 
         def run_tick():
             snap = solver.prepare(resources)
             gets = solver.solve(snap)
             solver.apply(resources, snap, gets, return_grants=False)
 
-        if self._resident_eligible(resources):
-            from doorman_tpu.solver.resident import ResidentOverflow
+        if not self._resident_eligible(resources):
+            if not self._native_store:
+                return None
+            self._stream_check_all = True
+            return run_tick
+        from doorman_tpu.solver.resident import ResidentOverflow
 
-            wide_ids = self._wide_ids
-            lane_res = [
-                r for r in resources
-                if algo_kind_for(r.template) != AlgoKind.PRIORITY_BANDS
-            ]
-            narrow_res = [r for r in lane_res if r.id not in wide_ids]
-            wide_res = [r for r in lane_res if r.id in wide_ids]
-            prio_res = [
-                r for r in resources
-                if algo_kind_for(r.template) == AlgoKind.PRIORITY_BANDS
-            ]
-            if self._streams is not None:
-                # Wide and priority rows solve outside the delta-tracked
-                # narrow path: their subscribers are checked every tick
-                # (the narrow rows keep the changed-rid filter).
-                self._stream_force_ids = (
-                    {r.id for r in wide_res} | {r.id for r in prio_res}
-                )
-            # Resolved HERE, on the event loop, so solver/resources/
-            # epoch stay mutually consistent under a concurrent
-            # mastership flip (see _resident_step).
-            resident = self._resident_solver() if narrow_res else None
-            wide = self._resident_wide_solver() if wide_res else None
-            if not narrow_res:
-                self._resident_pipe.drop()
-            if not wide_res:
-                self._resident_wide_pipe.drop()
-            epoch = self._config_epoch
+        wide_ids = self._wide_ids
+        lane_res = [
+            r for r in resources
+            if algo_kind_for(r.template) != AlgoKind.PRIORITY_BANDS
+        ]
+        narrow_res = [r for r in lane_res if r.id not in wide_ids]
+        wide_res = [r for r in lane_res if r.id in wide_ids]
+        prio_res = [
+            r for r in resources
+            if algo_kind_for(r.template) == AlgoKind.PRIORITY_BANDS
+        ]
+        if self._streams is not None:
+            # Wide and priority rows solve outside the delta-tracked
+            # narrow path: their subscribers are checked every tick
+            # (the narrow rows keep the changed-rid filter).
+            self._stream_force_ids = (
+                {r.id for r in wide_res} | {r.id for r in prio_res}
+            )
+        # Resolved HERE, on the event loop, so solver/resources/
+        # epoch stay mutually consistent under a concurrent
+        # mastership flip (see _resident_step).
+        resident = self._resident_solver() if narrow_res else None
+        wide = self._resident_wide_solver() if wide_res else None
+        if not narrow_res:
+            self._resident_pipe.drop()
+        if not wide_res:
+            self._resident_wide_pipe.drop()
+        epoch = self._config_epoch
 
-            def resident_or_fallback():
-                try:
-                    if narrow_res:
-                        self._resident_step(resident, narrow_res, epoch)
-                    if wide_res:
-                        # Lane resources wider than the dense bucket cap
-                        # tick through the chunked solver (their own
-                        # device tables; the solves are independent).
-                        self._resident_wide_step(wide, wide_res, epoch)
-                    if prio_res:
-                        # PRIORITY_BANDS resources tick through the
-                        # BatchSolver's priority part (group caps couple
-                        # only these rows, so the two solves are
-                        # independent); the lane subset keeps the
-                        # resident fast path.
-                        snap = solver.prepare(prio_res)
-                        gets = solver.solve(snap)
-                        solver.apply(
-                            prio_res, snap, gets, return_grants=False
-                        )
-                except ResidentOverflow:
-                    # A narrow lane resource outgrew the dense bucket
-                    # mid-tick: force a re-partition (it lands in the
-                    # wide set next tick) and run this tick through the
-                    # BatchSolver (correct at any width). BOTH in-flight
-                    # handles are dropped, not just the narrow one: a
-                    # pre-overflow wide handle collected after this
-                    # fallback would overwrite the fresher batch-applied
-                    # grants with one-tick-stale ones (the chunk-version
-                    # guard only catches membership changes, not value
-                    # staleness). Dropping an uncollected handle is
-                    # documented as benign.
-                    log.warning(
-                        "%s: resident bucket overflow; re-partitioning "
-                        "wide resources", self.id,
+        def resident_or_fallback():
+            try:
+                if narrow_res:
+                    self._resident_step(resident, narrow_res, epoch)
+                if wide_res:
+                    # Lane resources wider than the dense bucket cap
+                    # tick through the chunked solver (their own
+                    # device tables; the solves are independent).
+                    self._resident_wide_step(wide, wide_res, epoch)
+                if prio_res:
+                    # PRIORITY_BANDS resources tick through the
+                    # BatchSolver's priority part (group caps couple
+                    # only these rows, so the two solves are
+                    # independent); the lane subset keeps the
+                    # resident fast path.
+                    snap = solver.prepare(prio_res)
+                    gets = solver.solve(snap)
+                    solver.apply(
+                        prio_res, snap, gets, return_grants=False
                     )
-                    # Executor-thread write, but serialized: the loop
-                    # awaits this callable under _tick_lock, and the
-                    # only reader (_resident_eligible) runs at the next
-                    # tick's start, after the await completes.
-                    self._resident_ok_key = None  # doorman: allow[lock-discipline]
-                    self._resident_pipe.drop()
-                    self._resident_wide_pipe.drop()
-                    # The fallback tick applied grants with no delta
-                    # tracking (and dropped handles lost theirs).
-                    self._stream_check_all = True  # doorman: allow[lock-discipline] same serialization as _resident_ok_key
-                    run_tick()
+            except ResidentOverflow:
+                # A narrow lane resource outgrew the dense bucket
+                # mid-tick: force a re-partition (it lands in the
+                # wide set next tick) and run this tick through the
+                # BatchSolver (correct at any width). BOTH in-flight
+                # handles are dropped, not just the narrow one: a
+                # pre-overflow wide handle collected after this
+                # fallback would overwrite the fresher batch-applied
+                # grants with one-tick-stale ones (the chunk-version
+                # guard only catches membership changes, not value
+                # staleness). Dropping an uncollected handle is
+                # documented as benign.
+                log.warning(
+                    "%s: resident bucket overflow; re-partitioning "
+                    "wide resources", self.id,
+                )
+                # Executor-thread write, but serialized: the loop
+                # awaits this callable under _tick_lock, and the
+                # only reader (_resident_eligible) runs at the next
+                # tick's start, after the await completes.
+                self._resident_ok_key = None  # doorman: allow[lock-discipline]
+                self._resident_pipe.drop()
+                self._resident_wide_pipe.drop()
+                # The fallback tick applied grants with no delta
+                # tracking (and dropped handles lost theirs).
+                self._stream_check_all = True  # doorman: allow[lock-discipline] same serialization as _resident_ok_key
+                run_tick()
 
-            # copy_context: executor threads don't inherit contextvars,
-            # and the solver's phase spans must nest under the tick span.
-            ctx = contextvars.copy_context()
-            await loop.run_in_executor(None, ctx.run, resident_or_fallback)
-        elif self._native_store:
-            self._stream_check_all = True
-            ctx = contextvars.copy_context()
-            await loop.run_in_executor(None, ctx.run, run_tick)
-        else:
-            self._stream_check_all = True
-            snap = solver.prepare(resources)
-            ctx = contextvars.copy_context()
-            gets = await loop.run_in_executor(None, ctx.run, solver.solve, snap)
-            solver.apply(resources, snap, gets, return_grants=False)
-        if self._profiling and self._ticks_done >= self.profile_ticks:
+        return resident_or_fallback
+
+    def _profile_between_ticks(self) -> None:
+        """--profile-dir's one-shot capture, started and stopped between
+        ticks (tick lock held): it starts after the first applied tick,
+        so compiles stay out of it, and stops after `profile_ticks`
+        more. Capture trouble (another trace already active, no backend)
+        is logged and never blocks solving."""
+        if not self.profile_dir or self._profile_done:
+            return
+        done = self._ticks_done
+        if not self._profiling:
+            if done < 1:
+                return
+            import jax
+
+            try:
+                jax.profiler.start_trace(self.profile_dir)
+            except Exception:
+                log.exception("%s: profiler capture unavailable", self.id)
+                self._profile_done = True
+                return
+            self._profiling = True
+            self._profile_from = done
+        elif done >= self._profile_from + self.profile_ticks:
             self._stop_profiler()
             log.info(
                 "%s: wrote a JAX profiler trace of %d ticks to %s",
-                self.id, solver.ticks, self.profile_dir,
+                self.id, done - self._profile_from, self.profile_dir,
             )
 
     def _stop_profiler(self) -> None:

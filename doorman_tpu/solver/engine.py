@@ -42,6 +42,7 @@ from typing import Callable, Dict, List, Sequence, Tuple
 import numpy as np
 
 from doorman_tpu.core.resource import Resource, algo_kind_for, static_param
+from doorman_tpu.obs import trace as trace_mod
 from doorman_tpu.obs.phases import PhaseRecorder
 from doorman_tpu.utils import dispatch as dispatch_mod
 
@@ -1174,12 +1175,16 @@ class PipelinedTicker:
 
     def step(self, solver, resources, config_epoch: int = 0) -> TickHandle:
         """Collect the oldest in-flight tick once the pipeline is full,
-        then dispatch the next."""
-        while len(self._queue) >= self.depth:
-            s, h = self._queue.popleft()
-            if s is solver:
-                s.collect(h)
-        handle = solver.dispatch(resources, config_epoch)
+        then dispatch the next. Each stage is one span, so the engine's
+        time outside its phase laps shows per stage."""
+        tracer = trace_mod.default_tracer()
+        with tracer.span("engine.collect", cat="tick"):
+            while len(self._queue) >= self.depth:
+                s, h = self._queue.popleft()
+                if s is solver:
+                    s.collect(h)
+        with tracer.span("engine.dispatch", cat="tick"):
+            handle = solver.dispatch(resources, config_epoch)
         self._queue.append((solver, handle))
         return handle
 

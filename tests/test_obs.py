@@ -235,7 +235,9 @@ def test_debug_frontend_page_renders_inline_pool():
 
 
 def test_batch_tick_profiler_trace(tmp_path):
-    """--profile-dir writes a JAX profiler trace of the first ticks."""
+    """--profile-dir writes a JAX profiler trace of `profile_ticks`
+    ticks, started and stopped between ticks after the first applied
+    one, and the capture holds the program's tick spans."""
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -256,9 +258,20 @@ def test_batch_tick_profiler_trace(tmp_path):
         r.wants = 10.0
         await server.GetCapacity(req, None)
         await server.tick_once()
+        assert server._profiling  # started after the first tick
         await server.tick_once()
         assert not server._profiling
 
     asyncio.run(body())
-    traces = list(tmp_path.rglob("*"))
-    assert any(p.is_file() for p in traces), "no profiler trace written"
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    from jax.profiler import ProfileData
+
+    names = [
+        ev.name
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for ev in line.events
+    ]
+    # The second tick alone, with its sections.
+    assert names.count("server.tick") == 1
+    assert names.count("tick.partition") == 1
