@@ -95,6 +95,9 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.dm_clean.argtypes = [ctypes.c_void_p, ctypes.c_int32,
                              ctypes.c_double]
     lib.dm_sums.argtypes = [ctypes.c_void_p, ctypes.c_int32, _F64P]
+    lib.dm_sums_rows.restype = None
+    lib.dm_sums_rows.argtypes = [ctypes.c_void_p, _I32P, ctypes.c_int64,
+                                 _F64P]
     lib.dm_get.restype = ctypes.c_int32
     lib.dm_get.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64,
                            _F64P]
@@ -264,6 +267,18 @@ class StoreEngine:
     def max_leases(self) -> int:
         """Largest per-resource lease count (one O(R) C call)."""
         return self._lib.dm_max_leases(self._ptr)
+
+    def sums(self, rids: np.ndarray) -> np.ndarray:
+        """[n, 4] float64 aggregates of the given resources in one locked
+        C call: (sum_has, sum_wants, subclient count, #leases) per row,
+        the `NativeLeaseStore._sums` row; an invalid rid reads zeros."""
+        rids = np.ascontiguousarray(rids, np.int32)
+        out = np.empty((len(rids), 4), np.float64)
+        self._lib.dm_sums_rows(
+            self._ptr, rids.ctypes.data_as(_I32P), len(rids),
+            out.ctypes.data_as(_F64P),
+        )
+        return out
 
     def pack(self, order: List["NativeLeaseStore"]) -> Tuple[
         np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray,

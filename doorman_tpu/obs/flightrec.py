@@ -37,6 +37,11 @@ import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
 
+import numpy as np
+
+from doorman_tpu.native import NativeLeaseStore
+from doorman_tpu.obs.trace import default_tracer
+
 log = logging.getLogger(__name__)
 
 __all__ = ["ENV_DUMP_DIR", "FlightRecorder", "store_digest"]
@@ -51,19 +56,60 @@ def store_digest(resources: Dict[str, object]) -> str:
     sum_has, sum_wants, lease count per resource). O(#resources): the
     stores maintain running sums, so this never walks leases. Two
     states that diverge in aggregate grant mass diverge here — the
-    cheap "did the stores move?" pin a dump reader diffs first."""
-    items = [
-        (
-            rid,
-            round(float(res.capacity), 6),
-            round(float(res.store.sum_has), 6),
-            round(float(res.store.sum_wants), 6),
-            len(res.store),
+    cheap "did the stores move?" pin a dump reader diffs first.
+
+    When every store is a `NativeLeaseStore` of one engine the sums come
+    from one locked C call (`StoreEngine.sums`), else from each store's
+    attributes; either way the same columns go through one hash, so
+    equal store states give equal digests whatever backs them: sha256
+    over the NUL-joined sorted resource ids, then capacity, sum_has and
+    sum_wants rounded to 6 decimals as little-endian float64 (a row
+    each), then the lease counts as little-endian int64."""
+    with default_tracer().span("flightrec.digest", cat="tick") as span:
+        ids = sorted(resources)
+        res = [resources[i] for i in ids]
+        stores = [r.store for r in res]
+        n = len(ids)
+        capacity = np.fromiter(
+            (r.capacity for r in res), np.float64, count=n
         )
-        for rid, res in sorted(resources.items())
-    ]
-    payload = json.dumps(items, separators=(",", ":")).encode()
-    return hashlib.sha256(payload).hexdigest()[:16]
+        engine = _one_engine(stores)
+        if engine is not None:
+            rows = engine.sums(
+                np.fromiter((s._rid for s in stores), np.int32, count=n)
+            )
+            has, wants, leases = rows[:, 0], rows[:, 1], rows[:, 3]
+        else:
+            rows = np.array(
+                [(s.sum_has, s.sum_wants, len(s)) for s in stores],
+                np.float64,
+            ).reshape(n, 3)
+            has, wants, leases = rows[:, 0], rows[:, 1], rows[:, 2]
+        if span is not None:
+            span.args = {
+                "path": "per_store" if engine is None else "bulk",
+                "resources": n,
+            }
+        h = hashlib.sha256("\0".join(ids).encode())
+        # + 0.0 folds -0.0 into 0.0: equal values, equal bytes.
+        h.update(
+            (np.round(np.stack([capacity, has, wants]), 6) + 0.0)
+            .astype("<f8").tobytes()
+        )
+        h.update(leases.astype("<i8").tobytes())
+        return h.hexdigest()[:16]
+
+
+def _one_engine(stores: List[object]):
+    """The `StoreEngine` behind every store, or None when any store is
+    not a `NativeLeaseStore` of that one engine (or there is none)."""
+    if not stores or not isinstance(stores[0], NativeLeaseStore):
+        return None
+    engine = stores[0]._engine
+    for s in stores:
+        if not isinstance(s, NativeLeaseStore) or s._engine is not engine:
+            return None
+    return engine
 
 
 class FlightRecorder:

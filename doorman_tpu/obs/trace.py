@@ -109,6 +109,9 @@ KNOWN_SPAN_NAMES = frozenset({
     "tick.audit",
     "tick.flightrec",
     "tick.admission",
+    # The store digest inside tick.flightrec (obs/flightrec.py), its
+    # args the gather that ran ("bulk" or "per_store") and the count.
+    "flightrec.digest",
     "engine.collect",
     "engine.dispatch",
     "server.parent_refresh",

@@ -9,12 +9,16 @@ import json
 import os
 import urllib.request
 
+import pytest
+
 import tests.conftest  # noqa: F401
 
+from doorman_tpu import native
 from doorman_tpu.chaos.plan import FaultEvent, FaultPlan
 from doorman_tpu.chaos.runner import ChaosRunner
 from doorman_tpu.obs.debug import DebugServer
 from doorman_tpu.obs.flightrec import FlightRecorder, store_digest
+from doorman_tpu.obs.trace import default_tracer
 from doorman_tpu.server.config import parse_yaml_config
 from doorman_tpu.server.election import TrivialElection
 from doorman_tpu.server.server import CapacityServer
@@ -107,6 +111,143 @@ def test_store_digest_tracks_grant_mass():
     assert a == store_digest({"r0": Res(5.0)})  # stable
     assert a != store_digest({"r0": Res(6.0)})  # moves with grants
     assert len(a) == 16
+
+
+class AggStore:
+    """A store double holding only the three aggregates the digest reads."""
+
+    def __init__(self, has, wants, leases):
+        self.sum_has, self.sum_wants, self.leases = has, wants, leases
+
+    def __len__(self):
+        return self.leases
+
+
+class AggRes:
+    def __init__(self, capacity, has, wants, leases):
+        self.capacity = capacity
+        self.store = AggStore(has, wants, leases)
+
+
+def _agg_state():
+    return {
+        "r0": AggRes(100.0, 5.0, 10.0, 2),
+        "r1": AggRes(40.0, 1.5, 3.25, 1),
+    }
+
+
+def _lease_resources(store_factory, clock):
+    """Three resources holding the same leases whatever the store."""
+    from doorman_tpu.core.resource import Resource
+    from doorman_tpu.proto import doorman_pb2 as pb
+
+    out = {}
+    for r in range(3):
+        t = pb.ResourceTemplate()
+        t.identifier_glob = f"res{r}"
+        t.capacity = 100.0 + 7.5 * r
+        t.algorithm.kind = pb.Algorithm.PROPORTIONAL_SHARE
+        t.algorithm.lease_length = 60
+        t.algorithm.refresh_interval = 5
+        res = Resource(f"res{r}", t, clock=clock, store_factory=store_factory)
+        for c in range(r + 2):
+            res.store.assign(f"c{c}", 60.0, 5.0, 0.1 * (c + r), 1.3 * c,
+                             c + 1)
+        res.store.release("c0")
+        out[res.id] = res
+    return out
+
+
+needs_native = pytest.mark.skipif(
+    not native.native_available(), reason="native store build unavailable"
+)
+
+
+def _digest_spans(tracer):
+    return [e.args for e in tracer.snapshot() if e.name == "flightrec.digest"]
+
+
+@pytest.fixture
+def tracer():
+    """The process-global tracer enabled and cleared; left disabled and
+    empty for the other tests."""
+    tr = default_tracer()
+    tr.clear()
+    tr.enable()
+    yield tr
+    tr.disable()
+    tr.clear()
+
+
+@needs_native
+def test_store_digest_equal_for_native_and_python_stores():
+    clock = lambda: 1000.0  # noqa: E731
+    engine = native.StoreEngine(clock=clock)
+    cc = _lease_resources(engine.store, clock)
+    py = _lease_resources(None, clock)
+    assert type(cc["res0"].store).__name__ == "NativeLeaseStore"
+    assert type(py["res0"].store).__name__ == "LeaseStore"
+    assert store_digest(cc) == store_digest(py)
+
+
+@needs_native
+def test_store_digest_is_stable_across_calls():
+    clock = lambda: 1000.0  # noqa: E731
+    engine = native.StoreEngine(clock=clock)
+    cc = _lease_resources(engine.store, clock)
+    first = store_digest(cc)
+    assert all(store_digest(cc) == first for _ in range(3))
+    assert store_digest(_agg_state()) == store_digest(_agg_state())
+
+
+@pytest.mark.parametrize("change", [
+    "capacity", "sum_has", "sum_wants", "leases", "add", "remove",
+])
+def test_store_digest_moves_with_each_aggregate(change):
+    state = _agg_state()
+    before = store_digest(state)
+    if change == "capacity":
+        state["r1"].capacity += 1e-3
+    elif change == "add":
+        state["r2"] = AggRes(0.0, 0.0, 0.0, 0)
+    elif change == "remove":
+        del state["r1"]
+    else:
+        store = state["r1"].store
+        setattr(store, change, getattr(store, change) + 1)
+    assert store_digest(state) != before
+
+
+def test_store_digest_rounds_to_six_decimals():
+    state = _agg_state()
+    before = store_digest(state)
+    state["r0"].store.sum_has += 1e-9
+    state["r1"].capacity -= 1e-9
+    assert store_digest(state) == before
+
+
+@needs_native
+def test_store_digest_native_path_is_one_engine_call(monkeypatch, tracer):
+    clock = lambda: 1000.0  # noqa: E731
+    engine = native.StoreEngine(clock=clock)
+    cc = _lease_resources(engine.store, clock)
+    py = _lease_resources(None, clock)
+    expected = store_digest(py)
+    assert _digest_spans(tracer) == [
+        {"path": "per_store", "resources": 3}
+    ]
+
+    def per_store_read(self):
+        raise AssertionError("per-store aggregate read on the bulk path")
+
+    monkeypatch.setattr(native.NativeLeaseStore, "_sums", per_store_read)
+    assert store_digest(cc) == expected
+    assert _digest_spans(tracer)[-1] == {"path": "bulk", "resources": 3}
+    # A store from another engine makes the set mixed: per-store reads.
+    cc["res9"] = _lease_resources(native.StoreEngine(clock=clock).store,
+                                  clock)["res0"]
+    with pytest.raises(AssertionError, match="per-store"):
+        store_digest(cc)
 
 
 # ----------------------------------------------------------------------

@@ -385,6 +385,63 @@ def test_regrant_updates_has_without_dirtying_or_restamping():
     assert store.sum_has == 7.5
 
 
+def test_engine_sums_match_per_store_sums():
+    """StoreEngine.sums reads every resource's aggregates in one call:
+    row by row the per-store `_sums()`, through assigns, refreshes, a
+    release, an expiry sweep and a dense grant write-back."""
+    clock = FakeClock()
+    engine = native.StoreEngine(clock=clock)
+    stores = [engine.store(f"r{i}") for i in range(5)]
+    rids = np.array([s._rid for s in stores], np.int32)
+
+    def check():
+        rows = engine.sums(rids)
+        assert rows.shape == (5, 4) and rows.dtype == np.float64
+        for s, row in zip(stores, rows):
+            np.testing.assert_array_equal(row, s._sums())
+        return rows
+
+    for i, s in enumerate(stores):
+        for j in range(i + 2):  # expiries 110 + 10 j
+            s.assign(f"c{j}", 10.0 + 10.0 * j, 5.0, 0.5 * j,
+                     3.0 * j + i, 1 + j % 3)
+    check()
+
+    handles = np.array([engine.client_handle(f"c{j}") for j in range(3)],
+                       np.int64)
+    assert engine.bulk_refresh(
+        np.full(3, stores[2]._rid, np.int32), handles,
+        np.full(3, 1e12), np.full(3, 5.0), np.array([7.0, 8.0, 9.0]),
+    ) == 3
+    check()
+
+    stores[3].release("c1")
+    check()
+
+    clock.t = 125.0  # c0 and c1 lapse except where refreshed
+    assert engine.clean_all() > 0
+    rows = check()
+    assert rows[0, 3] == 0 and rows[2, 3] == 4
+
+    wants, _, _, act, counts, versions = engine.pack_rows(rids, 8)
+    grants = np.where(act.astype(bool), 0.5 * wants + 1.0, 0.0)
+    assert engine.apply_dense(
+        rids, grants, np.zeros(5, np.uint8), versions
+    ) == 5
+    rows = check()
+    np.testing.assert_allclose(rows[:, 0], grants.sum(axis=1))
+
+
+def test_engine_sums_out_of_range_rid_reads_zeros():
+    engine = native.StoreEngine(clock=FakeClock())
+    store = engine.store("real")
+    store.assign("c0", 60.0, 5.0, 1.0, 2.0, 3)
+    rows = engine.sums(np.array([999, -1, store._rid], np.int32))
+    np.testing.assert_array_equal(rows[:2], np.zeros((2, 4)))
+    np.testing.assert_array_equal(rows[2], [1.0, 2.0, 3.0, 1.0])
+    assert engine.sums(np.zeros(0, np.int32)).shape == (0, 4)
+
+
 def test_out_of_range_resource_handles_are_noops():
     """Every extern entry point must treat an out-of-range resource
     handle as a no-op (skip / return 0 / zero-fill), never as an

@@ -126,6 +126,19 @@ def test_tick_span_tree(tracer):
                                              "engine.dispatch")
 
 
+def test_flightrec_digest_span_reads_the_bulk_gather(tracer):
+    """The store digest is a child of tick.flightrec, not a section of
+    its own, and on the native store it gathers in one engine call."""
+    tracer.enable()
+    run_ticks(2)
+    by_id, _ = tree(tracer.snapshot())
+    digests = [e for e in by_id.values() if e.name == "flightrec.digest"]
+    assert len(digests) == 2
+    for e in digests:
+        assert by_id[e.parent_id].name == "tick.flightrec"
+        assert e.args == {"path": "bulk", "resources": 2}
+
+
 def test_tick_records_nothing_without_tracer_or_capture(tracer):
     assert not trace_mod.capture_active()
     run_ticks(2)
