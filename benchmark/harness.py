@@ -203,13 +203,45 @@ class CompileCounter:
         return out
 
 
+def engines(server) -> list:
+    """[(component, engine)] for the resident tick engines the server
+    runs now: the dense one, and the chunked one for lane resources wider
+    than the dense bucket cap. The server creates each on first need."""
+    return [(e.component, e)
+            for e in (server._resident, server._resident_wide)
+            if e is not None]
+
+
+def lap_totals(server) -> dict:
+    """{component: its cumulative phase laps}, taken before a tick."""
+    return {name: dict(e.phase_s) for name, e in engines(server)}
+
+
+def traced_kernels(server) -> list:
+    """The kernels the engines traced, sorted; empty with none."""
+    return sorted(set().union(*(e.traced_kernels
+                                for _, e in engines(server))))
+
+
 def tick_record(server, before: dict, start: float, wall: float) -> dict:
-    solver = server._resident
-    rec = {"start": start, "wall_s": wall, "phases": {}, "scope_rows": 0}
-    if solver is not None:
-        rec["phases"] = {k: v - before.get(k, 0.0)
-                         for k, v in solver.phase_s.items()}
-        rec["scope_rows"] = int(solver.last_scope.get("rows", 0))
+    """One tick: `engines` holds each engine's lap deltas against
+    `before` (an engine created inside the tick counts from zero) and its
+    `last_scope`; `phases` sums the deltas per phase name over the
+    engines. `scope_rows` is the dense engine's `last_scope["rows"]` (0
+    with none): resources in its scope, the unit `scope_rows.tick` and
+    the dense kernel's roofline read. The chunked engine counts chunk
+    rows of its slot width; a reader of it takes
+    `engines["resident_wide"]["scope"]`."""
+    rec = {"start": start, "wall_s": wall, "phases": {}, "scope_rows": 0,
+           "engines": {}}
+    for name, e in engines(server):
+        was = before.get(name, {})
+        laps = {k: v - was.get(k, 0.0) for k, v in e.phase_s.items()}
+        rec["engines"][name] = {"phases": laps, "scope": dict(e.last_scope)}
+        for k, v in laps.items():
+            rec["phases"][k] = rec["phases"].get(k, 0.0) + v
+        if name == "resident":
+            rec["scope_rows"] = int(e.last_scope.get("rows", 0))
     return rec
 
 
@@ -291,12 +323,11 @@ class GcWatch:
 
 
 def program_keys(server) -> set:
-    """Shape keys of the tick programs the resident engine holds (a new
+    """Shape keys of the tick programs the resident engines hold (a new
     one is a shape met for the first time in this process), without
-    the lane set."""
-    fns = getattr(server._resident, "_tick_fns", None) or {}
-    return {tuple(x for x in k if not isinstance(x, frozenset))
-            for k in fns}
+    the lane set, each led by its engine's component."""
+    return {(name,) + tuple(x for x in k if not isinstance(x, frozenset))
+            for name, e in engines(server) for k in e._tick_fns}
 
 
 async def drive_ticks(server, leases, engine, spec, seed, seconds, trace,
@@ -324,8 +355,7 @@ async def drive_ticks(server, leases, engine, spec, seed, seconds, trace,
                 batch = write()
         else:
             batch = 0
-        solver = server._resident
-        before = dict(solver.phase_s) if solver is not None else {}
+        before = lap_totals(server)
         n_events = len(counter.events)
         keys = program_keys(server)
         t = time.perf_counter()
@@ -346,7 +376,7 @@ async def drive_ticks(server, leases, engine, spec, seed, seconds, trace,
                 + ", ".join(f"{k} {v}" for k, v in rec["phases"].items()
                             if v > 1e-3)
                 + f"; scope {rec['scope_rows']}; new programs "
-                f"{sorted(new_keys)}; compile events "
+                f"{sorted(new_keys, key=repr)}; compile events "
                 f"{counter.seconds(t, t + wall)}; gc {gc_s} s in {gc_n} "
                 f"({gc2} of generation 2)")
         return rec
@@ -429,7 +459,7 @@ async def drive_ticks(server, leases, engine, spec, seed, seconds, trace,
                 "new_programs": sum(t["new_programs"] for t in ticks)}
     r.memory_peak = memory_peak(r.devices)
     if trace:
-        r.trace = prof.read(sorted(server._resident.traced_kernels))
+        r.trace = prof.read(traced_kernels(server))
     for _ in range(config["settle_ticks"]):
         await server.tick_once()
     r.settle_s = time.perf_counter() - t1

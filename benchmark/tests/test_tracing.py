@@ -1,6 +1,7 @@
-"""The trace reduction: busy union, idle gaps named by host span, kernel
-time by name, ops counted once when nested; on hand-made rows and on one
-tick recorded on the chip (`data/trace_rows.json.gz`)."""
+"""The trace reduction: busy union, idle gaps named by the innermost host
+span, kernel time by name, ops counted once when nested; on hand-made
+rows and on one tick recorded on the chip (`data/trace_rows.json.gz`).
+The program's spans change the gaps' names and nothing else."""
 
 import gzip
 import json
@@ -46,6 +47,35 @@ def test_reduce_hand_made():
     assert out["gaps"][1] == ["bench.tick", pytest.approx(100e-9)]
 
 
+def program_spans():
+    """The program's annotations in the two ticks of rows(): the first
+    tick's engine work on the executor's thread, the second's flight
+    record; and a host event that is no annotation of either."""
+    return [
+        ["/host:CPU", "python", "server.tick", 5.0, 385.0],
+        ["/host:CPU", "python", "tick.offload", 10.0, 290.0],
+        ["/host:CPU", "executor", "tick.engine", 20.0, 260.0],
+        ["/host:CPU", "python", "server.tick", 510.0, 380.0],
+        ["/host:CPU", "python", "tick.partition", 520.0, 100.0],
+        ["/host:CPU", "python", "tick.flightrec", 650.0, 240.0],
+        ["/host:CPU", "python", "flightrec.digest", 660.0, 200.0],
+        ["/host:CPU", "python", "PjitFunction(tick)", 0.0, 1000.0],
+    ]
+
+
+def test_reduce_names_gaps_by_the_innermost_program_span():
+    old = tracing.reduce(rows(), ["solve_dense_pallas"])
+    new = tracing.reduce(rows() + program_spans(), ["solve_dense_pallas"])
+    assert {k: v for k, v in new.items() if k != "gaps"} == \
+        {k: v for k, v in old.items() if k != "gaps"}
+    assert [g[1] for g in new["gaps"]] == [g[1] for g in old["gaps"]]
+    # [250, 950]: the second bench.tick > server.tick > tick.flightrec
+    # (240 against the partition's 100) > flightrec.digest; [0, 100]:
+    # the first tick's chain down to the engine on the other thread.
+    assert [g[0] for g in new["gaps"]] == ["flightrec.digest",
+                                           "tick.engine"]
+
+
 def test_reduce_nothing_to_read():
     assert tracing.reduce([r for r in rows() if r[0] != DEV]) is None
     assert tracing.reduce(
@@ -83,3 +113,50 @@ def test_recorded_trace():
                                        roofline.peaks("TPU v5 lite"))
     assert bound == "memory"
     assert 0 < least / out["kernel_s"] <= 1.0
+
+
+def test_recorded_trace_with_program_spans():
+    """Program spans laid into the recorded ticks (a server.tick inside
+    each bench.tick, a partition then a flight record inside that): every
+    number is the recording's, and the gaps, as long as before, are
+    named by the spans."""
+    rec = json.loads(gzip.open(DATA, "rt").read())
+    extra = []
+    for r in rec["rows"]:
+        if r[2] == "bench.tick":
+            t0, d = r[3] + 0.01 * r[4], 0.98 * r[4]
+            extra += [["/host:CPU", "python", "server.tick", t0, d],
+                      ["/host:CPU", "python", "tick.partition", t0, 0.3 * d],
+                      ["/host:CPU", "python", "tick.flightrec",
+                       t0 + 0.4 * d, 0.6 * d]]
+    out = tracing.reduce(rec["rows"] + extra, rec["kernels"])
+    want = rec["expected"]
+    for key in ("window_s", "busy_s", "kernel_s", "devices"):
+        assert out[key] == want[key]
+    assert out["ops"] == want["ops"]
+    assert [g[1] for g in out["gaps"]] == [g[1] for g in want["gaps"]]
+    names = {g[0] for g in out["gaps"]}
+    assert names <= {"bench.tick", "server.tick", "tick.partition",
+                     "tick.flightrec"}
+    assert names & {"tick.partition", "tick.flightrec"}
+
+
+def test_extract_keeps_the_program_annotations(tmp_path):
+    """A CPU capture: the program's spans and the benchmark's annotations
+    come out as rows, another annotation does not."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.traced"):
+            with jax.profiler.TraceAnnotation("server.tick"):
+                with jax.profiler.TraceAnnotation("tick.flightrec"):
+                    jax.numpy.ones(4).block_until_ready()
+            with jax.profiler.TraceAnnotation("elsewhere.work"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    names = {r[2] for r in tracing.extract(str(tmp_path))
+             if not r[0].startswith(tracing.DEVICE_PREFIX)}
+    assert {"bench.traced", "server.tick", "tick.flightrec"} <= names
+    assert "elsewhere.work" not in names

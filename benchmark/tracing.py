@@ -1,8 +1,11 @@
 """From a profiler trace to the numbers the per-layer readers use.
 
 `extract` reads the `.xplane.pb` that `jax.profiler` wrote and keeps the
-device planes' `XLA Ops` events and the benchmark's own host annotations
-(names starting with `bench.`) as plain rows. `reduce` turns rows into:
+device planes' `XLA Ops` events and the host annotations, the
+benchmark's own (`bench.`) and the program's spans (`server.tick`,
+`tick.`, `engine.`, `stream.`, `flightrec.`; `doorman_tpu/obs/trace.py`
+mirrors each into an annotation while a capture runs), as plain rows.
+`reduce` turns rows into:
 
   window_s   length of the `bench.traced` annotation (the traced part
              of the measured window)
@@ -11,8 +14,10 @@ device planes' `XLA Ops` events and the benchmark's own host annotations
   ops        device seconds per operation, outermost events only (the
              trace also lists the ops inside a while loop's body, one
              event per iteration, nested in the loop's own event)
-  gaps       idle intervals of the device, each named by the benchmark
-             host annotation that overlaps it most
+  gaps       idle intervals of the device, the longest first, each named
+             by the innermost host annotation on the chain that overlaps
+             it most: the annotation that overlaps it most, then within
+             it the one inside that overlaps it most, and so on down
   kernel_s   device seconds of the kernels' events that began inside the
              window (the launches of the traced ticks)
 
@@ -27,7 +32,8 @@ import os
 
 DEVICE_PREFIX = "/device:"
 OPS_LINE = "XLA Ops"
-ANNOTATION_PREFIX = "bench."
+ANNOTATION_PREFIXES = ("bench.", "server.tick", "tick.", "engine.",
+                       "stream.", "flightrec.")
 WINDOW = "bench.traced"
 
 
@@ -47,7 +53,7 @@ def extract(trace_dir: str) -> list:
             if device and line.name != OPS_LINE:
                 continue
             for ev in line.events:
-                if device or ev.name.startswith(ANNOTATION_PREFIX):
+                if device or ev.name.startswith(ANNOTATION_PREFIXES):
                     rows.append([plane.name, line.name, ev.name,
                                  float(ev.start_ns), float(ev.duration_ns)])
     return rows
@@ -116,16 +122,25 @@ def reduce(rows: list, kernel_names=(), top: int = 10) -> "dict | None":
             s, e = max(r[3], lo), min(r[3] + r[4], hi)
             if e > s:
                 ops[label] = ops.get(label, 0.0) + (e - s)
-    host = [r for r in rows if r[2].startswith(ANNOTATION_PREFIX)
+    host = [r for r in rows if r[2].startswith(ANNOTATION_PREFIXES)
             and r[2] != WINDOW]
 
     def name_gap(s, e):
-        best, best_ns = "none", 0.0
-        for r in host:
-            ov = min(e, r[3] + r[4]) - max(s, r[3])
-            if ov > best_ns:
-                best, best_ns = r[2], ov
-        return best
+        """Down the chain of annotations nested in one another, at each
+        level the one that overlaps [s, e] most (the longer on a tie, so
+        the descent passes through it to what it holds)."""
+        def overlap(r):
+            return min(e, r[3] + r[4]) - max(s, r[3])
+
+        name, a, b = "none", float("-inf"), float("inf")
+        pool = [r for r in host if overlap(r) > 0]
+        while True:
+            inside = [r for r in pool if a <= r[3] and r[3] + r[4] <= b]
+            if not inside:
+                return name
+            r = max(inside, key=lambda r: (overlap(r), r[4]))
+            pool.remove(r)
+            name, a, b = r[2], r[3], r[3] + r[4]
 
     gaps.sort(key=lambda g: g[0] - g[1])
     n = len(planes)
