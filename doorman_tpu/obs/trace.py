@@ -18,7 +18,10 @@ Design constraints, in order:
     process's garbage-collection pauses (complete events named
     `GC_EVENT`) record into the ring even with the tracer disabled, so
     every capture — the benchmark's traced run, an operator's
-    `--profile-dir` — holds the program's own timeline.
+    `--profile-dir` — holds the program's own timeline. A capture grows
+    the ring to `CAPTURE_CAPACITY` events, so a window of thousands of
+    short ticks keeps every span; the ring keeps that size after, for
+    whoever reads the capture's spans.
   * one clock with the device trace: while a capture records, every
     context-managed span also enters a `jax.profiler.TraceAnnotation` of
     the same name, so the span sits on the capture's host timeline,
@@ -168,6 +171,10 @@ KNOWN_INSTANT_NAMES = frozenset({
 # The complete event a garbage-collection pause records while a capture
 # runs (args: the generation), parented to the span it interrupted.
 GC_EVENT = "gc"
+
+# The least ring a capture records into: a tick records ~20 events, so
+# 30 s of 3.5 ms ticks is ~170k; an event is ~200 B.
+CAPTURE_CAPACITY = 1 << 20
 
 # The process time axis: perf_counter at import. Chrome trace `ts` must
 # be monotonic; wall clocks step and skew.
@@ -359,7 +366,7 @@ class Tracer:
         self._tids: Dict[int, int] = {}
         self._tnames: Dict[int, str] = {}
         # The gc.callbacks hook, live only while a capture records (see
-        # _hook_gc); _gc_t0 is the running pause's start.
+        # _on_capture); _gc_t0 is the running pause's start.
         self._gc_hook = self._on_gc
         self._gc_hooked = False
         self._gc_t0: Optional[float] = None
@@ -388,7 +395,7 @@ class Tracer:
         a profiler capture is running."""
         capturing = _is_enabled()
         if capturing is not self._gc_hooked:
-            self._hook_gc(capturing)
+            self._on_capture(capturing)
         return capturing or self.enabled
 
     def span(self, name: str, cat: str = "", args: Optional[dict] = None,
@@ -400,7 +407,7 @@ class Tracer:
         extracted from gRPC metadata on the server side of a hop."""
         mirror = _is_enabled()  # inline: this is the hot off path
         if mirror is not self._gc_hooked:
-            self._hook_gc(mirror)
+            self._on_capture(mirror)
         if not (mirror or self.enabled):
             return _NOOP
         return _ActiveSpan(self, name, cat, args, parent, mirror)
@@ -476,12 +483,17 @@ class Tracer:
 
     # -- internals -----------------------------------------------------
 
-    def _hook_gc(self, on: bool) -> None:
+    def _on_capture(self, on: bool) -> None:
         """Keep the gc hook in step with the capture flag: it is in
         gc.callbacks only while a capture records. A hook left in for
         good would cost a Python call per collection, and a busy server
-        collects about a hundred times a tick."""
+        collects about a hundred times a tick. A capture's start grows
+        the ring to CAPTURE_CAPACITY (an append racing the swap, at the
+        capture's first event, may be lost)."""
         with self._open_lock:
+            if on and self.capacity < CAPTURE_CAPACITY:
+                self.capacity = CAPTURE_CAPACITY
+                self._events = deque(self._events, maxlen=CAPTURE_CAPACITY)
             if on and self._gc_hook not in gc.callbacks:
                 gc.callbacks.append(self._gc_hook)
             elif not on and self._gc_hook in gc.callbacks:

@@ -57,6 +57,23 @@ bottleneck at 1M leases, and the whole point of this layout):
             (narrow-solver freshness at low churn); a byte budget keeps
             scattered churn from degenerating into full-table delivery.
 
+Tick programs: the staged blocks are keyed on power-of-two buckets of
+the tick's dirty counts (Dw wants-only slots from 1,024, Df full slots
+from 256, Sb delivered rows from 32), so a backlog meets O(log) shapes
+and not one per multiple. A tick whose dirty slots exceed
+WIDE_SCATTER_MAX stages the whole table's columns instead (one
+fixed-shape merge program over the resident tables, then the floor-sized
+tick program with empty blocks), so a backlog of any size meets programs
+the warm-up already compiled: per lane set and delivery/scope shape,
+Dw takes at most log2(WIDE_SCATTER_MAX / 1024) + 1 values and the merge
+program is one per table layout. Every dirty slot is in its tick's
+solve on either path.
+
+The fused staging buffer lays each block out by byte plane (byte 0 of
+every element, then byte 1, ...), and the program reassembles elements
+with shifts: a u8[n, k] -> wider bitcast makes the TPU compile time grow
+with n (~0.5 ms a slot), which a planar decode does not.
+
 Write-back safety: chunk membership versions are read after the slot
 drain and before the pack (StoreEngine.chunk_versions), so an apply's
 expected version can lag the device state but never lead it — a
@@ -83,13 +100,56 @@ from doorman_tpu.solver.engine import (
     TickEngineBase,
     TickHandle,
     bf16_exact,
-    ceil_to,
     compact_index_dtype,
     count_launch,
     pow2_bucket,
 )
 from doorman_tpu.solver.engine import _BF16
-from doorman_tpu.solver.resident import _ceil_to  # noqa: F401 (compat)
+
+# Most dirty slots (wants-only + full) one tick scatters; beyond it the
+# tick stages the whole table (see the module docstring). One bucket:
+# the steady tick stages tens of slots, and a backlog past ~65 ms of a
+# 1M-client stream takes the whole table's fixed-shape program, so every
+# program a tick can meet is met by a short warm-up.
+WIDE_SCATTER_MAX = 1024
+
+
+def _fused_bytes(blocks: Sequence[np.ndarray]) -> np.ndarray:
+    """The fused staging buffer: the blocks back to back along the last
+    axis, each laid out by byte plane (byte 0 of every element, then
+    byte 1, ...). Mesh blocks are [n_dev, n]: one row per shard."""
+    parts = []
+    for b in blocks:
+        b = np.ascontiguousarray(b)
+        lead, n = b.shape[:-1], b.shape[-1]
+        planes = b.view(np.uint8).reshape(lead + (n, b.dtype.itemsize))
+        parts.append(np.swapaxes(planes, -1, -2).reshape(lead + (-1,)))
+    return np.concatenate(parts, axis=-1)
+
+
+def _unpack_fused(buf, layout):
+    """Inverse of _fused_bytes for one buffer row: `layout` is
+    [(elements, dtype)] in block order; each element is reassembled
+    from its byte planes with shifts and a same-width bitcast."""
+    import jax
+    import jax.numpy as jnp
+
+    out = []
+    o = 0
+    for n, dt in layout:
+        dt = jnp.dtype(dt)
+        k = dt.itemsize
+        planes = buf[o : o + n * k].reshape(k, n)
+        o += n * k
+        if dt == jnp.bool_:
+            out.append(planes[0] != 0)
+            continue
+        word_t = jnp.dtype(f"uint{8 * k}")
+        word = planes[0].astype(word_t)
+        for b in range(1, k):
+            word = word | (planes[b].astype(word_t) << (8 * b))
+        out.append(jax.lax.bitcast_convert_type(word, dt))
+    return out
 
 
 class WideResidentSolver(TickEngineBase):
@@ -377,30 +437,28 @@ class WideResidentSolver(TickEngineBase):
         return tick
 
     def _fused_layout(self, Dw: int, Df: int, Sb: int, use_bf16: bool):
-        """Static byte layout of the wide fused staging buffer (shared
-        between the host pack and the executable's unpack): flat slot
-        index blocks, value blocks, the delivery row set, and the
-        active flags as raw uint8 last (no alignment constraint)."""
-        idt_size = int(np.dtype(self._idx_dtype).itemsize)
-        itemsize = int(self._dtype.itemsize)
-        wval_item = 2 if use_bf16 else itemsize
-        sizes = (
-            Dw * idt_size,   # w_idx
-            Dw * wval_item,  # w_val (bf16 when exact)
-            Df * idt_size,   # f_idx
-            Df * itemsize,   # f_w
-            Df * itemsize,   # f_h
-            Df * itemsize,   # f_s
-            Sb * 4,          # sel (int32)
-            Df,              # f_a (uint8)
+        """[(elements, dtype)] of the wide fused staging buffer, in the
+        order the host packs it (_fused_bytes) and the executable
+        unpacks it (_unpack_fused): flat slot index blocks, value
+        blocks, the delivery row set, and the active flags."""
+        idt, dt = self._idx_dtype, self._dtype
+        return (
+            (Dw, idt),  # w_idx
+            (Dw, _BF16 if use_bf16 else dt),  # w_val (bf16 when exact)
+            (Df, idt),  # f_idx
+            (Df, dt),  # f_w
+            (Df, dt),  # f_h
+            (Df, dt),  # f_s
+            (Sb, np.int32),  # sel
+            (Df, np.bool_),  # f_a
         )
-        return sizes, idt_size, wval_item, itemsize
 
     def _tick_fn_fused(self, Dw: int, Df: int, Sb: int, lanes: frozenset,
                        use_bf16: bool):
         """One-launch fused wide tick: the eight staged blocks arrive
-        as ONE uint8 buffer, bitcast apart at static offsets in-program
-        (see ResidentDenseSolver._tick_fn_fused for the idiom and the
+        as ONE uint8 buffer, unpacked by byte plane at static offsets
+        in-program (_unpack_fused; see ResidentDenseSolver._tick_fn_fused
+        for the idiom and the
         byte-identity argument — every scatter/solve op here is the
         round-trip executable's)."""
         key = ("fused", Dw, Df, Sb, lanes, use_bf16, self._idx_dtype)
@@ -409,7 +467,6 @@ class WideResidentSolver(TickEngineBase):
             return fn
 
         import jax
-        import jax.numpy as jnp
         from functools import partial
 
         from doorman_tpu.solver.dense import (
@@ -419,47 +476,15 @@ class WideResidentSolver(TickEngineBase):
 
         Rp, W = self._Rp, self._W
         dtype = self._dtype
-        jdtype = jnp.dtype(dtype)
         out_dtype = self._out_dtype
         row_seg = self._row_seg_d
-        sizes, idt_size, wval_item, itemsize = self._fused_layout(
-            Dw, Df, Sb, use_bf16
-        )
-        idt_j = jnp.dtype(self._idx_dtype)
-
-        def unpack(buf):
-            o = 0
-            parts = []
-            for n in sizes:
-                parts.append(buf[o : o + n])
-                o += n
-            w_idx = jax.lax.bitcast_convert_type(
-                parts[0].reshape(-1, idt_size), idt_j
-            )
-            w_val = jax.lax.bitcast_convert_type(
-                parts[1].reshape(-1, wval_item),
-                jnp.bfloat16 if use_bf16 else jdtype,
-            )
-            f_idx = jax.lax.bitcast_convert_type(
-                parts[2].reshape(-1, idt_size), idt_j
-            )
-            f_w, f_h, f_s = (
-                jax.lax.bitcast_convert_type(
-                    p.reshape(-1, itemsize), jdtype
-                )
-                for p in parts[3:6]
-            )
-            sel_idx = jax.lax.bitcast_convert_type(
-                parts[6].reshape(-1, 4), jnp.int32
-            )
-            f_a = parts[7] != 0
-            return w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
+        layout = self._fused_layout(Dw, Df, Sb, use_bf16)
 
         @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def tick(wants, has, sub, act, buf, cap, kind, learn, statc):
             (
-                w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
-            ) = unpack(buf)
+                w_idx, w_val, f_idx, f_w, f_h, f_s, sel_idx, f_a
+            ) = _unpack_fused(buf, layout)
             wants = (
                 wants.reshape(-1)
                 .at[w_idx].set(w_val.astype(dtype))
@@ -521,48 +546,16 @@ class WideResidentSolver(TickEngineBase):
 
         Rp, W = self._Rp, self._W
         dtype = self._dtype
-        jdtype = jnp.dtype(dtype)
         out_dtype = self._out_dtype
-        sizes, idt_size, wval_item, itemsize = self._fused_layout(
-            Dw, Df, Sb, use_bf16
-        )
-        idt_j = jnp.dtype(self._idx_dtype)
+        layout = self._fused_layout(Dw, Df, Sb, use_bf16)
         Mv = -(-Scb // W)  # moved-mask rows appended to the slab
-
-        def unpack(buf):
-            o = 0
-            parts = []
-            for n in sizes:
-                parts.append(buf[o : o + n])
-                o += n
-            w_idx = jax.lax.bitcast_convert_type(
-                parts[0].reshape(-1, idt_size), idt_j
-            )
-            w_val = jax.lax.bitcast_convert_type(
-                parts[1].reshape(-1, wval_item),
-                jnp.bfloat16 if use_bf16 else jdtype,
-            )
-            f_idx = jax.lax.bitcast_convert_type(
-                parts[2].reshape(-1, idt_size), idt_j
-            )
-            f_w, f_h, f_s = (
-                jax.lax.bitcast_convert_type(
-                    p.reshape(-1, itemsize), jdtype
-                )
-                for p in parts[3:6]
-            )
-            sel_idx = jax.lax.bitcast_convert_type(
-                parts[6].reshape(-1, 4), jnp.int32
-            )
-            f_a = parts[7] != 0
-            return w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
 
         @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def tick(wants, has, sub, act, buf, scope_buf, cap, kind,
                  learn, statc):
             (
-                w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
-            ) = unpack(buf)
+                w_idx, w_val, f_idx, f_w, f_h, f_s, sel_idx, f_a
+            ) = _unpack_fused(buf, layout)
             wants = (
                 wants.reshape(-1)
                 .at[w_idx].set(w_val.astype(dtype))
@@ -643,46 +636,14 @@ class WideResidentSolver(TickEngineBase):
         Rp, W = self._Rp, self._W
         Rl = Rp // mr.n_dev
         dtype = self._dtype
-        jdtype = jnp.dtype(dtype)
         out_dtype = self._out_dtype
-        sizes, idt_size, wval_item, itemsize = self._fused_layout(
-            Dw, Df, Sb, use_bf16
-        )
-        idt_j = jnp.dtype(self._idx_dtype)
-
-        def unpack(buf):
-            o = 0
-            parts = []
-            for n in sizes:
-                parts.append(buf[o : o + n])
-                o += n
-            w_idx = jax.lax.bitcast_convert_type(
-                parts[0].reshape(-1, idt_size), idt_j
-            )
-            w_val = jax.lax.bitcast_convert_type(
-                parts[1].reshape(-1, wval_item),
-                jnp.bfloat16 if use_bf16 else jdtype,
-            )
-            f_idx = jax.lax.bitcast_convert_type(
-                parts[2].reshape(-1, idt_size), idt_j
-            )
-            f_w, f_h, f_s = (
-                jax.lax.bitcast_convert_type(
-                    p.reshape(-1, itemsize), jdtype
-                )
-                for p in parts[3:6]
-            )
-            sel_idx = jax.lax.bitcast_convert_type(
-                parts[6].reshape(-1, 4), jnp.int32
-            )
-            f_a = parts[7] != 0
-            return w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
+        layout = self._fused_layout(Dw, Df, Sb, use_bf16)
 
         def body(wants, has, sub, act, buf, scope_buf, cap, kind,
                  learn, statc):
             (
-                w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
-            ) = unpack(buf[0])
+                w_idx, w_val, f_idx, f_w, f_h, f_s, sel_idx, f_a
+            ) = _unpack_fused(buf[0], layout)
             wants = (
                 wants.reshape(-1)
                 .at[w_idx].set(w_val.astype(dtype), mode="drop")
@@ -794,49 +755,17 @@ class WideResidentSolver(TickEngineBase):
         Rp, W = self._Rp, self._W
         Rl = Rp // mr.n_dev
         dtype = self._dtype
-        jdtype = jnp.dtype(dtype)
         out_dtype = self._out_dtype
-        sizes, idt_size, wval_item, itemsize = self._fused_layout(
-            Dw, Df, Sb, use_bf16
-        )
-        idt_j = jnp.dtype(self._idx_dtype)
+        layout = self._fused_layout(Dw, Df, Sb, use_bf16)
         segsum, segmax = resident_chunk_reduces(
             self._mesh, self._row_seg_h, self._Sp, Rl
         )
 
-        def unpack(buf):
-            o = 0
-            parts = []
-            for n in sizes:
-                parts.append(buf[o : o + n])
-                o += n
-            w_idx = jax.lax.bitcast_convert_type(
-                parts[0].reshape(-1, idt_size), idt_j
-            )
-            w_val = jax.lax.bitcast_convert_type(
-                parts[1].reshape(-1, wval_item),
-                jnp.bfloat16 if use_bf16 else jdtype,
-            )
-            f_idx = jax.lax.bitcast_convert_type(
-                parts[2].reshape(-1, idt_size), idt_j
-            )
-            f_w, f_h, f_s = (
-                jax.lax.bitcast_convert_type(
-                    p.reshape(-1, itemsize), jdtype
-                )
-                for p in parts[3:6]
-            )
-            sel_idx = jax.lax.bitcast_convert_type(
-                parts[6].reshape(-1, 4), jnp.int32
-            )
-            f_a = parts[7] != 0
-            return w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
-
         def body(wants, has, sub, act, row_seg, buf, cap, kind, learn,
                  statc):
             (
-                w_idx, w_val, f_idx, f_w, f_h, f_s, f_a, sel_idx
-            ) = unpack(buf[0])
+                w_idx, w_val, f_idx, f_w, f_h, f_s, sel_idx, f_a
+            ) = _unpack_fused(buf[0], layout)
             wants = (
                 wants.reshape(-1)
                 .at[w_idx].set(w_val.astype(dtype), mode="drop")
@@ -1042,6 +971,15 @@ class WideResidentSolver(TickEngineBase):
             fpos += nf_i
         ph.lap("pack")
 
+        # A backlog past the ceiling merges into the resident tables
+        # whole (inside the staging lap); the tick then scatters nothing.
+        whole = n_w + n_f > WIDE_SCATTER_MAX
+        self.last_scope.update(slots=n_w + n_f, whole=int(whole))
+        if whole:
+            self._stage_whole(w_idx, w_val, f_idx, f_w, f_h, f_s, f_a)
+            w_idx, w_val, f_idx, f_w, f_h, f_s, f_a = (
+                a[:0] for a in (w_idx, w_val, f_idx, f_w, f_h, f_s, f_a)
+            )
         keep = np.zeros(n_sel, np.uint8)
         if n_sel:
             segs = self._row_seg_h[sel]
@@ -1050,12 +988,15 @@ class WideResidentSolver(TickEngineBase):
             return self._stage_mesh(
                 w_idx, w_val, f_idx, f_w, f_h, f_s, f_a,
                 sel, sel_rids, sel_chunks, versions, keep, now, ph,
-                scope,
+                scope, whole,
             )
 
-        Dw = ceil_to(n_w, 1024)
-        Df = ceil_to(n_f, 256)
-        Sb = ceil_to(n_sel, 32)
+        Dw = pow2_bucket(len(w_idx), 1024)
+        Df = pow2_bucket(len(f_idx), 256)
+        Sb = pow2_bucket(n_sel, 32)
+        self.last_scope["padded"] = (
+            Dw + Df + (self._Rp * W if whole else 0)
+        )
         pad_slot = self._R * W  # padding row slot 0
         idt = self._idx_dtype
 
@@ -1079,8 +1020,6 @@ class WideResidentSolver(TickEngineBase):
             padded(f_h, Df, 0),
             padded(f_s, Df, 0),
             sel_pad.astype(np.int32),
-            # Active flags last: raw uint8 bytes carry no alignment
-            # constraint in the fused buffer layout (_fused_layout).
             padded(f_a, Df, False),
         )
         cfg = self._config
@@ -1092,10 +1031,7 @@ class WideResidentSolver(TickEngineBase):
             # one uint8 buffer, one placement, one launch, one download
             # stream (see ResidentDenseSolver._launch's fused tail).
             use_bf16 = w_val_block.dtype != self._dtype
-            buf = np.concatenate(
-                [np.ascontiguousarray(b).view(np.uint8).ravel()
-                 for b in host_blocks]
-            )
+            buf = _fused_bytes(host_blocks)
             if scope is not None:
                 # Scoped staging: the group closure's whole chunk-row
                 # span, its compact segment map, and the scoped
@@ -1190,7 +1126,7 @@ class WideResidentSolver(TickEngineBase):
 
     def _stage_mesh(self, w_idx, w_val, f_idx, f_w, f_h, f_s, f_a,
                     sel, sel_rids, sel_chunks, versions, keep, now, ph,
-                    scope=None):
+                    scope=None, whole=False):
         """Mesh tail of the launch: slot scatters and the delivery set
         grouped by owning shard; per-shard blocks land only on their
         own device, the shard_mapped tick solves with the bit-stable
@@ -1227,9 +1163,12 @@ class WideResidentSolver(TickEngineBase):
             owner_sel, n_dev, [sel - owner_sel * Rl]
         )
 
-        Dw = ceil_to(int(counts_w.max()) if len(w_idx) else 1, 1024)
-        Df = ceil_to(int(counts_f.max()) if len(f_idx) else 1, 256)
-        Sb = ceil_to(int(counts_sel.max()) if n_sel else 1, 32)
+        Dw = pow2_bucket(int(counts_w.max()) if len(w_idx) else 1, 1024)
+        Df = pow2_bucket(int(counts_f.max()) if len(f_idx) else 1, 256)
+        Sb = pow2_bucket(int(counts_sel.max()) if n_sel else 1, 32)
+        self.last_scope["padded"] = n_dev * (Dw + Df) + (
+            self._Rp * W if whole else 0
+        )
         w_idx_b, w_val_b = pad_shard_blocks(
             counts_w, Dw, [(w_idx_l, span), (w_val_l, 0)]
         )
@@ -1308,17 +1247,9 @@ class WideResidentSolver(TickEngineBase):
             # Fused upload (see ResidentDenseSolver._stage_mesh): one
             # [n_dev, B] uint8 buffer, each shard's slice carrying its
             # eight staged blocks back to back in _fused_layout order.
-            n_dev_ax = w_idx_b.shape[0]
-            buf_host = np.concatenate(
-                [
-                    np.ascontiguousarray(b)
-                    .view(np.uint8).reshape(n_dev_ax, -1)
-                    for b in (
-                        w_idx_b, w_val_b, f_idx_b, f_w_b, f_h_b,
-                        f_s_b, sel_b, f_a_b,
-                    )
-                ],
-                axis=1,
+            buf_host = _fused_bytes(
+                (w_idx_b, w_val_b, f_idx_b, f_w_b, f_h_b, f_s_b, sel_b,
+                 f_a_b)
             )
         ph.lap("staging")
 
@@ -1398,6 +1329,57 @@ class WideResidentSolver(TickEngineBase):
             moved=moved_d,
             seq=self._seq,
         )
+
+    def _merge_fn(self):
+        """The whole-table merge: each staged column replaces the
+        resident one where its slot is dirty (flags: bits 0-1 the drain
+        level, 1 wants-only or 2 full; bit 2 the active flag), as the
+        scatter path's blocks would. Its shape is the table's alone."""
+        key = ("whole",)
+        fn = self._tick_fns.get(key)
+        if fn is not None:
+            return fn
+
+        import jax
+        import jax.numpy as jnp
+        from functools import partial
+
+        @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+        def merge(wants, has, sub, act, w, h, s, flags):
+            level = flags & 3
+            full = level >= 2
+            return (
+                jnp.where(level >= 1, w, wants),
+                jnp.where(full, h, has),
+                jnp.where(full, s, sub),
+                jnp.where(full, (flags & 4) != 0, act),
+            )
+
+        self._tick_fns[key] = merge
+        return merge
+
+    def _stage_whole(self, w_idx, w_val, f_idx, f_w, f_h, f_s, f_a):
+        """Stage a backlog past WIDE_SCATTER_MAX as the whole table's
+        columns (wants, has, subclients and one flags byte a slot: 13 B
+        at f32) and merge them into the resident tables on device."""
+        shape = (self._Rp, self._W)
+        n = self._Rp * self._W
+        w = np.zeros(n, self._dtype)
+        h = np.zeros(n, self._dtype)
+        s = np.zeros(n, self._dtype)
+        flags = np.zeros(n, np.uint8)
+        w[w_idx] = w_val
+        flags[w_idx] = 1
+        w[f_idx] = f_w
+        h[f_idx] = f_h
+        s[f_idx] = f_s
+        flags[f_idx] = 2 | (f_a.astype(np.uint8) << 2)
+        put = self._put_rows
+        self._wants, self._has, self._sub, self._act = self._merge_fn()(
+            self._wants, self._has, self._sub, self._act,
+            *(put(a.reshape(shape)) for a in (w, h, s, flags)),
+        )
+        count_launch()
 
     def _apply_grants(self, handle: TickHandle, gets: np.ndarray) -> int:
         return self._engine.apply_chunks(

@@ -386,3 +386,98 @@ def test_drain_remove_pack_interleaving_converges():
         wide.step(res_a)
         batch.tick(res_b)
     assert_close(all_leases(res_a), all_leases(res_b))
+
+
+# -- bounded tick programs: geometric buckets and the whole-table path ----
+
+SCATTER_MAX = 4096  # the test's ceiling: Dw buckets 1,024, 2,048, 4,096
+
+
+def backlog_churn(resources, n, rng, step):
+    """New wants for `n` distinct leases drawn over every resource, plus
+    one release, one new client and one client-reported `has` (slots
+    staged in full), so each tick stages about n + 4 dirty slots."""
+    keys = [(i, c) for i, res in enumerate(resources)
+            for c, _ in sorted(res.store.items())]
+    for k in sorted(rng.choice(len(keys), size=n, replace=False)):
+        i, c = keys[k]
+        lease = resources[i].store.get(c)
+        wants = 1 + (int(lease.wants) + int(rng.integers(0, 198))) % 199
+        resources[i].store.assign(c, 60.0, 5.0, lease.has, float(wants), 1)
+    i = step % len(resources)
+    res = resources[i]
+    res.store.release(sorted(c for c, _ in res.store.items())[step])
+    res.store.assign(f"n{step}_{i}", 60.0, 5.0, 0.0,
+                     float(rng.integers(1, 50)), 2)
+    c = sorted(c for c, _ in resources[-1].store.items())[-1]
+    lease = resources[-1].store.get(c)
+    resources[-1].store.assign(c, 60.0, 5.0, lease.has + 1.0, lease.wants,
+                               lease.subclients)
+
+
+@pytest.mark.parametrize(
+    "backlogs, mesh",
+    [
+        ([300, 700, 1000], False),
+        ([900, 1500, 3000, 3900, 600], False),
+        ([5000, 300, 6000, 2500, 800], False),
+        ([300, 2500, 5000], True),
+    ],
+    ids=["below_first_bucket", "across_two_edges", "beyond_ceiling",
+         "beyond_ceiling_mesh"],
+)
+def test_wide_backlogs_match_batch_solver_in_bounded_programs(
+        backlogs, mesh, monkeypatch):
+    """Dirty backlogs below the first scatter bucket, across two bucket
+    edges and past the ceiling (the whole table staged) track the
+    BatchSolver tick for tick; the tick programs per lane set keep to
+    log2(ceiling / 1,024) + 1 values of Dw and one whole-table merge."""
+    from doorman_tpu.parallel import make_mesh
+    from doorman_tpu.solver import resident_wide
+
+    monkeypatch.setattr(resident_wide, "WIDE_SCATTER_MAX", SCATTER_MAX)
+    t = [1000.0]
+    clock = lambda: t[0]
+    eng_a, res_a = make_world(clock, n_clients=1500, seed=5)
+    eng_b, res_b = make_world(clock, n_clients=1500, seed=5)
+    wide = WideResidentSolver(
+        eng_a, dtype=np.float64, clock=clock, rotate_ticks=1,
+        chunk_width=64, mesh=make_mesh() if mesh else None,
+    )
+    batch = BatchSolver(dtype=np.float64, clock=clock)
+    wide.step(res_a)  # rebuild: the whole table packed, nothing staged
+    batch.tick(res_b)
+    rng_a, rng_b = (np.random.default_rng(17) for _ in range(2))
+    wholes = []
+    for step, n in enumerate(backlogs):
+        backlog_churn(res_a, n, rng_a, step)
+        backlog_churn(res_b, n, rng_b, step)
+        t[0] += 1.0
+        handle = wide.dispatch(res_a)
+        scope = dict(wide.last_scope)
+        wide.collect(handle)
+        batch.tick(res_b)
+        assert_close(all_leases(res_a), all_leases(res_b), f"tick {step}")
+        assert n <= scope["slots"] <= n + 8
+        assert scope["whole"] == (scope["slots"] > SCATTER_MAX)
+        assert scope["padded"] >= scope["slots"]
+        if scope["whole"]:
+            floor = (8 if mesh else 1) * (1024 + 256)
+            assert scope["padded"] == wide._Rp * wide._W + floor
+        wholes.append(scope["whole"])
+    assert any(wholes) == (max(backlogs) > SCATTER_MAX)
+
+    dw_bound = int(np.log2(SCATTER_MAX / 1024)) + 1
+    groups = {}
+    for key in wide._tick_fns:
+        if key == ("whole",):
+            continue
+        # Dw is the key's first size; the rest fix the program family,
+        # delivery and scope shapes, and the lane set.
+        assert key[1] <= SCATTER_MAX, key
+        groups.setdefault((key[0],) + key[2:], set()).add(key[1])
+    assert groups
+    for family, dws in groups.items():
+        assert len(dws) <= dw_bound, (family, sorted(dws))
+    merges = [k for k in wide._tick_fns if k == ("whole",)]
+    assert len(merges) == int(any(wholes))

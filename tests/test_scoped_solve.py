@@ -421,7 +421,11 @@ def test_wide_straddling_chunk_closure():
     for _ in range(8):
         solver.step(resources, 0)
         t[0] += 1.0
-    assert solver.last_scope == {"rows": 0, "resources": 0}
+    # An empty scope, nothing staged: the floor-sized scatter blocks.
+    assert solver.last_scope == {
+        "rows": 0, "resources": 0, "slots": 0, "padded": 1024 + 256,
+        "whole": 0,
+    }
     # One slot of resource 0 (30 clients / width 8 -> 4 chunk rows).
     resources[0].store.assign("w0_3", 60.0, 5.0, 0.0, 99.0, 1)
     solver.step(resources, 0)

@@ -208,6 +208,28 @@ def test_tick_spans_under_profiler_capture(tracer, tmp_path):
         assert lo <= got[0][1] and got[0][1] + got[0][2] <= hi, name
 
 
+def test_capture_grows_the_ring(tmp_path):
+    """A capture grows a small ring to CAPTURE_CAPACITY, so it keeps more
+    spans than the ring held before, and they stay readable after it."""
+    import jax
+
+    tr = trace_mod.Tracer(capacity=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(10):
+            with tr.span("server.tick"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert tr.capacity == trace_mod.CAPTURE_CAPACITY
+    assert [e.name for e in tr.snapshot()] == ["server.tick"] * 10
+    with tr.span("server.tick"):  # off again: the ring keeps its size
+        pass
+    assert tr.capacity == trace_mod.CAPTURE_CAPACITY
+    assert len(tr.snapshot()) == 10
+    assert tr._gc_hook not in gc.callbacks
+
+
 def test_trace_module_needs_no_jax():
     """obs/trace.py stays importable without jax, and without it no
     capture is running."""
