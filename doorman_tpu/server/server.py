@@ -23,14 +23,14 @@ import asyncio
 import contextvars
 import logging
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import grpc
 
 from doorman_tpu.admission.policy import RETRY_AFTER_KEY, Shed
 from doorman_tpu.algorithms import Request
 from doorman_tpu.algorithms.kinds import AlgoKind
-from doorman_tpu.core.resource import Resource, algo_kind_for
+from doorman_tpu.core.resource import Resource
 from doorman_tpu.obs import metrics as metrics_mod
 from doorman_tpu.obs import trace as trace_mod
 from doorman_tpu.proto import doorman_pb2 as pb
@@ -81,6 +81,30 @@ def default_resource_template() -> pb.ResourceTemplate:
             learning_mode_duration=20,
         ),
     )
+
+
+class LanePartition(NamedTuple):
+    """The Tick driver's split of the resource registry, in registry
+    order: narrow lane resources (the dense resident solver), lane
+    resources wider than the dense bucket cap (the chunked solver) and
+    PRIORITY_BANDS resources (the BatchSolver's priority part). The
+    wide and priority ids are also the rows the stream fanout checks
+    every tick (`force_ids`): they solve outside the delta-tracked
+    narrow path."""
+
+    narrow: List[Resource]
+    wide: List[Resource]
+    prio: List[Resource]
+    wide_ids: frozenset
+    force_ids: frozenset
+
+    @property
+    def eligible(self) -> bool:
+        """Whether the resident path runs: any lane resource at all."""
+        return bool(self.narrow or self.wide)
+
+
+_NO_PARTITION = LanePartition([], [], [], frozenset(), frozenset())
 
 
 class CapacityServer(CapacityServicer):
@@ -228,8 +252,6 @@ class CapacityServer(CapacityServicer):
         self._tick_pipeline_depth = max(int(tick_pipeline_depth), 1)
         self._resident = None
         self._resident_pipe = PipelinedTicker(self._tick_pipeline_depth)
-        self._resident_ok_key = None
-        self._resident_ok = False
         # Admission-fused staging: the coalescer's windows pre-pack their
         # touched rows into the resident solver's staging cache, moving
         # the store pack off the tick's critical path (engine.FusedStaging;
@@ -258,11 +280,17 @@ class CapacityServer(CapacityServicer):
         # (ResidentOverflow, priority part) stay single-device.
         self._solver_mesh = mesh
         # Wide lane resources (wider than the dense bucket cap) tick
-        # through their own chunked resident solver; the partition is
-        # recomputed with the eligibility key.
+        # through their own chunked resident solver.
         self._resident_wide = None
         self._resident_wide_pipe = PipelinedTicker(self._tick_pipeline_depth)
-        self._wide_ids: set = set()
+        # The lane partition (native stores), rebuilt only when its key
+        # (config epoch, resource count) moves: the registry only grows
+        # between epoch bumps. ResidentOverflow clears the key alone,
+        # so the fused-staging hook keeps the last wide set until the
+        # next tick re-partitions. partition_rebuilds counts rebuilds.
+        self._partition = _NO_PARTITION
+        self._partition_key = None
+        self.partition_rebuilds = 0
         # Bumped whenever templates / learning windows / parent leases
         # change outside the stores; the resident solver caches its
         # config reads against it.
@@ -308,7 +336,6 @@ class CapacityServer(CapacityServicer):
         # solver parts, config epoch moves) force a full subscription
         # check instead of the engine's changed-rid filter.
         self._stream_check_all = True
-        self._stream_force_ids: set = set()
         self._stream_epoch_seen = -1
         self._rid_map_key = None
         self._rid_map: Dict[int, str] = {}
@@ -641,7 +668,10 @@ class CapacityServer(CapacityServicer):
         self._resident_pipe.drop()
         self._resident_wide = None
         self._resident_wide_pipe.drop()
-        self._resident_ok_key = None
+        # The partition's lists hold the wiped resources (and through
+        # their stores the old engine): drop them with the key.
+        self._partition = _NO_PARTITION
+        self._partition_key = None
         self._stream_check_all = True
         self._rid_map_key = None
         # The matcher's rid bindings belong to the replaced engine; the
@@ -826,12 +856,13 @@ class CapacityServer(CapacityServicer):
         ):
             return
         rids = []
+        wide_ids = self._partition.wide_ids
         for resource_id in resource_ids:
             res = self.resources.get(resource_id)
             if (
                 res is not None
-                and resource_id not in self._wide_ids
-                and algo_kind_for(res.template) != AlgoKind.PRIORITY_BANDS
+                and resource_id not in wide_ids
+                and res._decide_kind != AlgoKind.PRIORITY_BANDS
             ):
                 rids.append(res.store._rid)
         if rids:
@@ -854,36 +885,52 @@ class CapacityServer(CapacityServicer):
         if res is not None:
             solver.staging.invalidate(res.store._rid)
 
-    def _resident_eligible(self, resources: List[Resource]) -> bool:
+    def _lane_partition(self, resources: List[Resource]) -> LanePartition:
         """The resident path covers a native batch server's lane
         (non-PRIORITY_BANDS) resources; a mixed config keeps the
         resident fast path for the lane subset while the PRIORITY_BANDS
         resources (their own dense part, group caps) tick through the
         BatchSolver alongside it. Lane resources wider than the dense
         bucket cap take the chunked wide solver — there is no width
-        limit on the resident path. Recomputed only when the config
-        epoch or the resource set moves (ResidentOverflow forces a
-        re-partition between recomputes)."""
-        if not self._native_store:
-            return False
+        limit on the resident path. Rebuilt only when the config epoch
+        or the resource set moves (ResidentOverflow forces a
+        re-partition between rebuilds)."""
         key = (self._config_epoch, len(resources))
-        if key != self._resident_ok_key:
+        if key != self._partition_key:
+            import numpy as np
+
             from doorman_tpu.solver.batch import DENSE_MAX_K
 
-            self._resident_ok_key = key
-            lane = [
-                r
-                for r in resources
-                if algo_kind_for(r.template) != AlgoKind.PRIORITY_BANDS
-            ]
-            # A wide PRIORITY_BANDS resource (band aggregation is
-            # exactly the many-client use case) never enters a resident
-            # dense bucket; only lane resources partition by width.
-            self._wide_ids = {
-                r.id for r in lane if len(r.store) > DENSE_MAX_K
-            }
-            self._resident_ok = bool(lane)
-        return self._resident_ok
+            # Every lease count in one locked engine call: a len() per
+            # store is a call each.
+            rids = np.fromiter(
+                (r.store._rid for r in resources), np.int32,
+                count=len(resources),
+            )
+            leases = self._store_engine.sums(rids)[:, 3].tolist()
+            narrow, wide, prio = [], [], []
+            for r, n in zip(resources, leases):
+                # _decide_kind, set by Resource.load_config on every
+                # template change: reading the template costs
+                # microseconds a resource.
+                if r._decide_kind == AlgoKind.PRIORITY_BANDS:
+                    # A wide PRIORITY_BANDS resource (band aggregation
+                    # is exactly the many-client use case) never enters
+                    # a resident dense bucket; only lane resources
+                    # partition by width.
+                    prio.append(r)
+                elif n > DENSE_MAX_K:
+                    wide.append(r)
+                else:
+                    narrow.append(r)
+            wide_ids = frozenset(r.id for r in wide)
+            self._partition = LanePartition(
+                narrow, wide, prio, wide_ids,
+                wide_ids | frozenset(r.id for r in prio),
+            )
+            self._partition_key = key
+            self.partition_rebuilds += 1
+        return self._partition
 
     def _resident_step(self, solver, resources: List[Resource],
                        config_epoch: int) -> None:
@@ -992,10 +1039,15 @@ class CapacityServer(CapacityServicer):
     async def _tick_once_locked(self) -> None:
         if not self.resources:
             return
-        with trace_mod.default_tracer().span("tick.partition", cat="tick"):
+        with trace_mod.default_tracer().span(
+            "tick.partition", cat="tick"
+        ) as span:
             solver = self._get_solver()
             resources = list(self.resources.values())
+            rebuilds = self.partition_rebuilds
             work = self._tick_work(solver, resources)
+            if span is not None:
+                span.args = {"rebuilt": self.partition_rebuilds - rebuilds}
         if work is not None:
             await self._offload(work)
         else:
@@ -1024,39 +1076,24 @@ class CapacityServer(CapacityServicer):
 
     def _tick_work(self, solver, resources: List[Resource]):
         """The tick's engine work as one callable for the executor, with
-        the lanes partitioned here, on the event loop; None for a python
-        store, whose snapshot pack and write-back stay on the loop."""
+        the lane partition read here, on the event loop; None for a
+        python store, whose snapshot pack and write-back stay on the
+        loop."""
 
         def run_tick():
             snap = solver.prepare(resources)
             gets = solver.solve(snap)
             solver.apply(resources, snap, gets, return_grants=False)
 
-        if not self._resident_eligible(resources):
-            if not self._native_store:
-                return None
+        if not self._native_store:
+            return None
+        part = self._lane_partition(resources)
+        if not part.eligible:
             self._stream_check_all = True
             return run_tick
         from doorman_tpu.solver.resident import ResidentOverflow
 
-        wide_ids = self._wide_ids
-        lane_res = [
-            r for r in resources
-            if algo_kind_for(r.template) != AlgoKind.PRIORITY_BANDS
-        ]
-        narrow_res = [r for r in lane_res if r.id not in wide_ids]
-        wide_res = [r for r in lane_res if r.id in wide_ids]
-        prio_res = [
-            r for r in resources
-            if algo_kind_for(r.template) == AlgoKind.PRIORITY_BANDS
-        ]
-        if self._streams is not None:
-            # Wide and priority rows solve outside the delta-tracked
-            # narrow path: their subscribers are checked every tick
-            # (the narrow rows keep the changed-rid filter).
-            self._stream_force_ids = (
-                {r.id for r in wide_res} | {r.id for r in prio_res}
-            )
+        narrow_res, wide_res, prio_res = part.narrow, part.wide, part.prio
         # Resolved HERE, on the event loop, so solver/resources/
         # epoch stay mutually consistent under a concurrent
         # mastership flip (see _resident_step).
@@ -1106,14 +1143,14 @@ class CapacityServer(CapacityServicer):
                 )
                 # Executor-thread write, but serialized: the loop
                 # awaits this callable under _tick_lock, and the
-                # only reader (_resident_eligible) runs at the next
+                # only reader (_lane_partition) runs at the next
                 # tick's start, after the await completes.
-                self._resident_ok_key = None  # doorman: allow[lock-discipline]
+                self._partition_key = None  # doorman: allow[lock-discipline]
                 self._resident_pipe.drop()
                 self._resident_wide_pipe.drop()
                 # The fallback tick applied grants with no delta
                 # tracking (and dropped handles lost theirs).
-                self._stream_check_all = True  # doorman: allow[lock-discipline] same serialization as _resident_ok_key
+                self._stream_check_all = True  # doorman: allow[lock-discipline] same serialization as _partition_key
                 run_tick()
 
         return resident_or_fallback
@@ -1281,8 +1318,10 @@ class CapacityServer(CapacityServicer):
         rids = set(solver.take_changed_rids())
         if check_all:
             return None, True
+        # Wide and priority rows solve outside the delta-tracked narrow
+        # path: their subscribers are checked every tick.
         for resource_id in (
-            self._stream_force_ids | self._stream_learning_ids()
+            self._partition.force_ids | self._stream_learning_ids()
         ):
             res = self.resources.get(resource_id)
             if res is not None and hasattr(res.store, "_rid"):

@@ -257,9 +257,10 @@ resources:
                 await asyncio.sleep(0.05)
             assert server._resident is not None
             assert server._resident.ticks >= 3
-            assert server._resident_ok
+            assert server._partition.eligible
             width = len(res.store)
             assert width > DENSE_MAX_K - 200
+            rebuilds = server.partition_rebuilds
 
             errors = []
             stop = [False]
@@ -309,8 +310,10 @@ resources:
             # unharmed.
             assert server._resident_wide is not None
             assert server._resident_wide.ticks >= 3
-            assert server._resident_ok
-            assert "big" in server._wide_ids
+            assert server._partition.eligible
+            assert "big" in server._partition.wide_ids
+            # The overflow cleared the partition's key: one rebuild.
+            assert server.partition_rebuilds == rebuilds + 1
             assert not errors, errors[:5]
 
             # No grant lost or doubled across the switch: the store's

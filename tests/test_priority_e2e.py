@@ -248,7 +248,7 @@ def test_mixed_config_keeps_resident_path_for_lane_resources():
 
         # The resident path engaged for the lane subset...
         assert server._resident is not None and server._resident.ticks >= 1
-        assert server._resident_ok
+        assert server._partition.eligible
         # ...serving the lane resource through it (proportional: 100
         # capacity, wants 140+80 => scaled by 100/220, free-clamped)...
         plain = dict(server.resources["plain"].store.items())

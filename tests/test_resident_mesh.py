@@ -350,7 +350,7 @@ resources:
                 ) == (b[key].has, b[key].wants), (rid, key)
         assert mesh_srv._resident is not None
         assert mesh_srv._resident_wide is not None
-        assert "wide" in mesh_srv._wide_ids
+        assert "wide" in mesh_srv._partition.wide_ids
         for s in servers:
             await s.stop()
 

@@ -113,11 +113,11 @@ def test_wide_resource_partitions_to_chunked_solver(monkeypatch):
             # Partitioned directly — no overflow fallback needed.
             assert server._resident_wide is not None
             assert server._resident_wide.ticks >= 3
-            assert "wide" in server._wide_ids
+            assert "wide" in server._partition.wide_ids
             # The narrow resource kept the narrow resident solver.
             assert server._resident is not None
             assert server._resident.ticks >= 3
-            assert "narrow" not in server._wide_ids
+            assert "narrow" not in server._partition.wide_ids
 
             # Oversubscribed proportional share: grants scale to
             # capacity; the store conserves exactly.
@@ -170,7 +170,7 @@ def test_partition_boundary(monkeypatch, width, expect_wide):
                 if solver is not None and solver.ticks >= 2:
                     break
                 await asyncio.sleep(0.05)
-            assert ("wide" in server._wide_ids) == expect_wide
+            assert ("wide" in server._partition.wide_ids) == expect_wide
             solver = (
                 server._resident_wide if expect_wide else server._resident
             )

@@ -112,6 +112,8 @@ def test_tick_span_tree(tracer):
         for e in children:
             assert tick.ts <= e.ts and e.ts + e.dur <= tick.ts + tick.dur
         assert sum(e.dur for e in children) <= tick.dur
+        (partition,) = [e for e in children if e.name == "tick.partition"]
+        assert partition.span_id not in kids
         (offload,) = [e for e in children if e.name == "tick.offload"]
         (engine,) = kids[offload.span_id]
         assert engine.name == "tick.engine"
@@ -124,6 +126,9 @@ def test_tick_span_tree(tracer):
     for lap in laps:
         assert by_id[lap.parent_id].name in ("engine.collect",
                                              "engine.dispatch")
+    # The first tick builds the lane partition, the second reuses it.
+    partitions = [e for e in events if e.name == "tick.partition"]
+    assert [e.args for e in partitions] == [{"rebuilt": 1}, {"rebuilt": 0}]
 
 
 def test_flightrec_digest_span_reads_the_bulk_gather(tracer):

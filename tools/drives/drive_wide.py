@@ -89,7 +89,7 @@ resources:
     print(f"first tick (compile) {time.perf_counter() - t0:.1f}s",
           flush=True)
     assert server._resident_wide is not None, "wide solver not engaged"
-    assert "shared" in server._wide_ids
+    assert "shared" in server._partition.wide_ids
     assert server._resident is not None, "narrow solver not engaged"
     chunks = server._resident_wide._R
     assert chunks == -(-WIDE_CLIENTS // 4096), chunks
